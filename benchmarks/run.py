@@ -90,6 +90,10 @@ def main() -> None:
     args = ap.parse_args()
     chosen = args.only.split(",") if args.only else SUITES
 
+    # every suite runs in this one process (a child would find the chip
+    # held), so one cache serves them all
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.obs import REGISTRY
 
     failed = []

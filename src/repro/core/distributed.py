@@ -22,8 +22,12 @@ Shard layout contract (device mirrors)
 --------------------------------------
 Every device mirror (``RoundRobinMirror``) is laid out ROUND-ROBIN:
 global row ``i`` lives on shard ``i % n_shards`` at local slot
-``i // n_shards``, in a ``(n_shards, capacity, *rest)`` buffer whose
-leading axis is sharded over the data axes.  A head-aligned append of
+``i // n_shards``, in a ``(n_shards * capacity, *rest)`` buffer whose
+row axis is sharded over the data axes — shard ``s`` holds the
+contiguous block ``[s * capacity, (s + 1) * capacity)``, so each
+shard's local block is ``(capacity, *rest)`` in its natural layout
+(a size-1 leading shard axis made the TPU compiler relayout the whole
+local block on every call).  A head-aligned append of
 ``d * n_shards`` rows therefore lands in slots
 ``[per_live, per_live + d)`` of EVERY shard — host->device traffic is
 O(chunk) and the resident corpus is never re-laid-out, unlike a
@@ -76,7 +80,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -99,9 +102,9 @@ def _encode_fn(mesh: Mesh, encoder, out_def, out_ndims):
     # axes replicated
     spec_out = jax.tree.unflatten(
         out_def, [P(axes, *([None] * (nd - 1))) for nd in out_ndims])
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         lambda x: encoder.encode(x), mesh=mesh, in_specs=(P(axes, None),),
-        out_specs=spec_out, check_rep=False))
+        out_specs=spec_out, check_vma=False))
 
 
 def encode_sharded(encoder, dataset, mesh: Mesh):
@@ -142,8 +145,7 @@ def rowwise_sharded(obj, method: str, rows, mesh: Mesh):
     pad = (-m) % n_shards
     if pad:
         rows = np.concatenate([rows, rows[-1:].repeat(pad, axis=0)])
-    sharded = jax.device_put(jnp.asarray(rows, jnp.float32),
-                             NamedSharding(mesh, P(axes, None)))
+    sharded = jax.device_put(rows, NamedSharding(mesh, P(axes, None)))
     return jax.tree.map(lambda l: np.asarray(l)[:m], fn(sharded))
 
 
@@ -163,9 +165,9 @@ def _repr_dists_fn(mesh: Mesh, pw, q_def, x_def, q_ndims, x_ndims):
     in_q = jax.tree.unflatten(q_def, [P(*([None] * nd)) for nd in q_ndims])
     in_x = jax.tree.unflatten(
         x_def, [P(axes, *([None] * (nd - 1))) for nd in x_ndims])
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         lambda rq, rx: pw(rq, rx), mesh=mesh, in_specs=(in_q, in_x),
-        out_specs=P(None, axes), check_rep=False))
+        out_specs=P(None, axes), check_vma=False))
 
 
 def repr_distances_sharded(encoder, rep_query, rep_data, mesh: Mesh,
@@ -196,9 +198,9 @@ def _repr_topk_fn(mesh: Mesh, pw, k: int, q_def, x_def, q_ndims, x_ndims):
     in_q = jax.tree.unflatten(q_def, [P(*([None] * nd)) for nd in q_ndims])
     in_x = jax.tree.unflatten(
         x_def, [P(axes, *([None] * (nd - 1))) for nd in x_ndims])
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(in_q, in_x),
-        out_specs=(P(None, None), P(None, None)), check_rep=False))
+        out_specs=(P(None, None), P(None, None)), check_vma=False))
 
 
 def repr_topk_sharded(encoder, rep_query, rep_data, mesh: Mesh, *,
@@ -228,63 +230,88 @@ def _shard_index(axes):
     return sid
 
 
+#: Rows per sharded encode call during ingest (256 MB of f32 at T=960)
+_ENCODE_ROWS = 1 << 16
+
+#: TPU vector lane width: raw-row mirrors pad their row width to a
+#: multiple of it (see ``RoundRobinMirror``)
+_LANES = 128
+
+
+def _row_spec(mesh: Mesh, ndim: int):
+    """Row-sharded spec of a ``(n_shards * cap, *rest)`` mirror buffer."""
+    return P(_data_axes(mesh), *([None] * (ndim - 1)))
+
+
 @lru_cache(maxsize=64)
 def _rr_place_fn(mesh: Mesh, ndim: int):
-    """Jitted in-place slot write: ``buf[:, start:start+d] = delta``,
-    donating the old buffer — the per-append device work is O(chunk)
-    window writes, never a corpus-wide concatenate."""
-    axes = _data_axes(mesh)
-    sh = NamedSharding(mesh, P(axes, *([None] * (ndim - 1))))
+    """Jitted in-place slot write: every shard writes its ``d`` delta
+    rows at local slot ``start``, donating the old buffer — the
+    per-append device work is O(chunk) window writes, never a
+    corpus-wide concatenate."""
+    spec = _row_spec(mesh, ndim)
 
-    @partial(jax.jit, out_shardings=sh, donate_argnums=0)
-    def place(buf, delta, start):
-        zeros = (0,) * (buf.ndim - 2)
-        return jax.lax.dynamic_update_slice(buf, delta, (0, start) + zeros)
+    def local(buf, delta, start):
+        zeros = (0,) * (buf.ndim - 1)
+        return jax.lax.dynamic_update_slice(buf, delta, (start,) + zeros)
 
-    return place
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(spec, spec, P()), out_specs=spec,
+        check_vma=False), donate_argnums=0)
 
 
 @lru_cache(maxsize=64)
-def _rr_grow_fn(mesh: Mesh, ndim: int):
-    """Jitted capacity growth (device-side zero-pad of the slot axis)."""
-    axes = _data_axes(mesh)
-    sh = NamedSharding(mesh, P(axes, *([None] * (ndim - 1))))
+def _rr_grow_fn(mesh: Mesh, ndim: int, new_cap: int):
+    """Jitted capacity growth: every shard zero-pads its local block to
+    ``new_cap`` slots on device."""
+    spec = _row_spec(mesh, ndim)
 
-    @partial(jax.jit, static_argnums=1, out_shardings=sh, donate_argnums=0)
-    def grow(buf, new_cap):
-        pad = [(0, 0)] * buf.ndim
-        pad[1] = (0, new_cap - buf.shape[1])
+    def local(buf):
+        pad = [(0, new_cap - buf.shape[0])] + [(0, 0)] * (buf.ndim - 1)
         return jnp.pad(buf, pad)
 
-    return grow
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(spec,), out_specs=spec,
+        check_vma=False), donate_argnums=0)
 
 
 class RoundRobinMirror:
     """Append-local device mirror of host rows, sharded round-robin.
 
     Global row ``i`` lives on shard ``i % n_shards`` at local slot
-    ``i // n_shards``; the device buffer is ``(n_shards, capacity,
-    *rest)`` with the leading axis sharded over the mesh data axes.  An
+    ``i // n_shards``; the device buffer is ``(n_shards * capacity,
+    *rest)`` with its row axis sharded over the mesh data axes, so shard
+    ``s`` holds rows ``[s * capacity, (s + 1) * capacity)`` of it.  An
     append of ``d * n_shards`` rows uploads exactly those rows
-    (O(chunk) host->device, counted in ``h2d_bytes``) into slots
+    (O(chunk) host->device, counted in ``h2d_bytes``), each shard's
+    share straight to its own device, into slots
     ``[per_live, per_live + d)`` of every shard — the resident corpus
     is never re-uploaded or re-laid-out, unlike a contiguous-range
     layout where every append shifts every shard boundary.  Capacity
     doubles device-side when exhausted (``jnp.pad``, no host traffic),
     so amortized append cost stays O(chunk).  Slots ``>= per_live`` are
     dead padding; every consumer masks them via the ``per_live``
-    scalar."""
+    scalar.
 
-    def __init__(self, mesh: Mesh, n_shards: int):
+    ``lanes``: when set, 2-D rows are zero-padded on the trailing axis
+    to a multiple of ``lanes`` at upload.  The raw-row mirrors use the
+    TPU lane width (128): for a row width that is not a multiple of it
+    (T = 960) the chip's default layout of the buffer is column-major,
+    and every row gather then relayouts the whole mirror first.  With
+    the padded width the default layout is row-major and a gather
+    reads only the gathered rows.  Consumers slice the padding off."""
+
+    def __init__(self, mesh: Mesh, n_shards: int, *, lanes: int = 0):
         self.mesh = mesh
         self.n_shards = int(n_shards)
-        self.buf = None                  # (S, cap, *rest) device array
+        self.lanes = int(lanes)
+        self.buf = None                  # (S * cap, *rest) device array
         self.per_live = 0                # live slots per shard
         self.h2d_bytes = 0               # host->device upload accounting
 
     @property
     def cap(self) -> int:
-        return 0 if self.buf is None else self.buf.shape[1]
+        return 0 if self.buf is None else self.buf.shape[0] // self.n_shards
 
     @property
     def live(self) -> int:
@@ -302,21 +329,28 @@ class RoundRobinMirror:
         if d == 0:
             return
         rest = rows.shape[1:]
-        # (d*S, ...) -> (S, d, ...): appended row j*S + s -> shard s,
-        # slot per_live + j
-        blk = np.ascontiguousarray(
-            rows.reshape((d, S) + rest).swapaxes(0, 1))
-        sh = NamedSharding(self.mesh, P(_data_axes(self.mesh),
-                                        *([None] * len(rest))))
-        dev = jax.device_put(blk, sh)
-        self.h2d_bytes += blk.nbytes
+        pad = (-rest[-1]) % self.lanes if self.lanes and rows.ndim == 2 \
+            else 0
+        if pad:
+            rest = (rest[0] + pad,)
+
+        # appended row j*S + s -> shard s, slot per_live + j: shard s's
+        # delta block is the strided slice rows[s::S], staged per device
+        def block(idx):
+            blk = rows[(idx[0].start or 0) // d::S]
+            return (np.pad(blk, ((0, 0), (0, pad))) if pad
+                    else np.ascontiguousarray(blk))
+
+        sh = NamedSharding(self.mesh, _row_spec(self.mesh, rows.ndim))
+        dev = jax.make_array_from_callback((S * d,) + rest, sh, block)
+        self.h2d_bytes += rows.nbytes
         if self.buf is None:
             self.buf = dev
         else:
             if self.per_live + d > self.cap:
                 new_cap = max(2 * self.cap, self.per_live + d)
-                self.buf = _rr_grow_fn(self.mesh, self.buf.ndim)(
-                    self.buf, new_cap)
+                self.buf = _rr_grow_fn(self.mesh, self.buf.ndim, new_cap)(
+                    self.buf)
             self.buf = _rr_place_fn(self.mesh, self.buf.ndim)(
                 self.buf, dev, jnp.int32(self.per_live))
         self.per_live += d
@@ -339,14 +373,13 @@ def _rr_bounds_fn(mesh: Mesh, pw, q_def, x_def, q_ndims, x_ndims):
         x_def, [P(axes, *([None] * (nd - 1))) for nd in x_ndims])
 
     def local(rq, rx, per):
-        rx = jax.tree.map(lambda l: l[0], rx)          # strip shard axis
         d = pw(rq, rx)                                 # (Q, cap)
         dead = jnp.arange(d.shape[1])[None, :] >= per
         return jnp.where(dead, jnp.inf, d)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(in_q, in_x, P()),
-        out_specs=P(None, axes), check_rep=False))
+        out_specs=P(None, axes), check_vma=False))
 
 
 @lru_cache(maxsize=64)
@@ -364,7 +397,6 @@ def _rr_topk_fn(mesh: Mesh, pw, k: int, n_shards: int,
         x_def, [P(axes, *([None] * (nd - 1))) for nd in x_ndims])
 
     def local(rq, rx, per):
-        rx = jax.tree.map(lambda l: l[0], rx)
         d = pw(rq, rx)                                 # (Q, cap)
         cap = d.shape[1]
         d = jnp.where(jnp.arange(cap)[None, :] >= per, jnp.inf, d)
@@ -381,9 +413,9 @@ def _rr_topk_fn(mesh: Mesh, pw, k: int, n_shards: int,
         return (jnp.take_along_axis(cand_d, best, axis=1),
                 jnp.take_along_axis(cand_i, best, axis=1))
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(in_q, in_x, P()),
-        out_specs=(P(None, None), P(None, None)), check_rep=False))
+        out_specs=(P(None, None), P(None, None)), check_vma=False))
 
 
 def _kernel_cand_d2(rows, qs):
@@ -406,33 +438,35 @@ def _rr_rows_verify_fn(mesh: Mesh, n_shards: int):
     axes = _data_axes(mesh)
 
     def local(x, q, c, per):
-        x = x[0]                                      # (cap, T) local
-        cap = x.shape[0]
+        cap = x.shape[0]                              # x: (cap, T_pad) local
         slot = c // n_shards
         valid = ((c >= 0) & (c % n_shards == _shard_index(axes))
                  & (slot < per))
-        rows = x[jnp.clip(slot, 0, cap - 1)]          # (Qa, B, T)
+        rows = x[jnp.clip(slot, 0, cap - 1), :q.shape[-1]]   # (Qa, B, T)
         d2 = _kernel_cand_d2(rows, q)
         # each candidate is owned by exactly one shard: min-merge
         return jax.lax.pmin(jnp.where(valid, d2, jnp.inf), axes)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
-        in_specs=(P(axes, None, None), P(None, None), P(None, None), P()),
-        out_specs=P(None, None), check_rep=False))
+        in_specs=(P(axes, None), P(None, None), P(None, None), P()),
+        out_specs=P(None, None), check_vma=False))
 
 
 def cand_dists_rows_rr(raw_buf, q_dev, cand, mesh: Mesh, n_shards: int,
                        per_live: int) -> np.ndarray:
     """True d_ED of candidate ROW ids against a round-robin raw mirror.
 
-    raw_buf: the mirror's (S, cap, T) device buffer.  q_dev: (Qa, T)
+    raw_buf: the mirror's (S * cap, T) device buffer.  q_dev: (Qa, T)
     replicated queries.  cand: (Qa, B) int ids, -1 padding.  Ids outside
     the mirrored head return +inf (the caller min-merges the host-side
-    tail).  Raw rows never leave the devices."""
-    d2 = _rr_rows_verify_fn(mesh, int(n_shards))(
-        raw_buf, q_dev, jnp.asarray(cand), jnp.int32(per_live))
-    return np.asarray(jnp.sqrt(jnp.maximum(d2, 0.0)))
+    tail).  Raw rows never leave the devices; only the (Qa, B) squared
+    distances do, and the square root is taken on the host exactly as
+    ``core.engine.kernel_verifier`` takes it, so the device and host
+    paths cannot differ by a backend's sqrt rounding."""
+    d2 = np.asarray(_rr_rows_verify_fn(mesh, int(n_shards))(
+        raw_buf, q_dev, jnp.asarray(cand), jnp.int32(per_live)))
+    return np.sqrt(np.maximum(d2, 0.0))
 
 
 @lru_cache(maxsize=64)
@@ -445,22 +479,21 @@ def _rr_windows_gather_fn(mesh: Mesh, n_shards: int, nw: int, stride: int,
     axes = _data_axes(mesh)
 
     def local(x, c, per):
-        x = x[0]                                      # (cap, T_src)
-        cap = x.shape[0]
+        cap = x.shape[0]                   # x: (cap, T_src [+ lane pad])
         row = jnp.where(c >= 0, c // nw, -1)
         start = (c % nw) * stride          # in-bounds even for c == -1
         slot = row // n_shards
         valid = ((c >= 0) & (row % n_shards == _shard_index(axes))
                  & (slot < per))
-        slab = x[jnp.clip(slot, 0, cap - 1)]          # (Qa, B, T_src)
+        slab = x[jnp.clip(slot, 0, cap - 1)]          # (Qa, B, T_pad)
         gat = start[..., None] + jnp.arange(m)[None, None, :]
         w = jnp.take_along_axis(slab, gat, axis=2)    # (Qa, B, m)
         return jax.lax.psum(jnp.where(valid[..., None], w, 0.0), axes)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
-        in_specs=(P(axes, None, None), P(None, None), P()),
-        out_specs=P(None, None, None), check_rep=False))
+        in_specs=(P(axes, None), P(None, None), P()),
+        out_specs=P(None, None, None), check_vma=False))
 
 
 def cand_dists_windows_rr(raw_buf, q_dev, cand, mesh: Mesh, *,
@@ -696,14 +729,23 @@ class ShardedRepSweep:
     # -- ingest -----------------------------------------------------------
     def _encode_chunk(self, rows: np.ndarray):
         """Sharded one-pass encode of a chunk (pad to shard multiple,
-        trim) — bit-identical to the unsharded row-wise encode."""
+        trim) — bit-identical to the unsharded row-wise encode.  The
+        host rows go straight to their shards, ``_ENCODE_ROWS`` at a
+        time, so neither one device nor the encoder's intermediates ever
+        hold the whole chunk."""
         from repro.store.symbolic import rep_leaves
-        m = rows.shape[0]
-        pad = (-m) % self.n_shards
-        if pad:
-            rows = np.concatenate([rows, rows[-1:].repeat(pad, axis=0)])
-        rep = encode_sharded(self.encoder, jnp.asarray(rows), self.mesh)
-        leaves = tuple(np.asarray(l)[:m] for l in rep_leaves(rep))
+        sh = NamedSharding(self.mesh, P(self.axes, None))
+        parts = []
+        for c0 in range(0, rows.shape[0], _ENCODE_ROWS):
+            blk = rows[c0:c0 + _ENCODE_ROWS]
+            m = blk.shape[0]
+            pad = (-m) % self.n_shards
+            if pad:
+                blk = np.concatenate([blk, blk[-1:].repeat(pad, axis=0)])
+            rep = encode_sharded(self.encoder, jax.device_put(blk, sh),
+                                 self.mesh)
+            parts.append(tuple(np.asarray(l)[:m] for l in rep_leaves(rep)))
+        leaves = tuple(np.concatenate(ls) for ls in zip(*parts))
         return leaves if isinstance(rep, tuple) else leaves[0]
 
     def ingest(self, rows) -> np.ndarray:
@@ -711,6 +753,8 @@ class ShardedRepSweep:
         rows = np.asarray(rows, np.float32)
         if rows.ndim == 1:
             rows = rows[None]
+        if rows.shape[0] == 0:
+            return np.empty(0, np.int64)
         return self.store.append(rows, rep=self._encode_chunk(rows))
 
     # -- device mirror ----------------------------------------------------
@@ -744,8 +788,8 @@ class ShardedRepSweep:
                     mir.append(l[self._head:head])
                 if self.mirror_raw:
                     if self._raw_mirror is None:
-                        self._raw_mirror = RoundRobinMirror(self.mesh,
-                                                            self.n_shards)
+                        self._raw_mirror = RoundRobinMirror(
+                            self.mesh, self.n_shards, lanes=_LANES)
                     self._raw_mirror.append(
                         self.store.data[self._head:head])
             self._tail_rep = (self._restructure(
@@ -762,6 +806,15 @@ class ShardedRepSweep:
         if self._raw_mirror is not None:
             total += self._raw_mirror.h2d_bytes
         return total
+
+    @property
+    def mirror_bytes(self) -> dict:
+        """Device bytes held by the mirrors, summed over shards (lane
+        padding and spare capacity included): ``{"rep": ..., "raw": ...}``."""
+        def held(m):
+            return 0 if m is None or m.buf is None else int(m.buf.nbytes)
+        return {"rep": sum(held(m) for m in (self._mirrors or ())),
+                "raw": held(self._raw_mirror)}
 
     def transfer_stats(self) -> dict:
         """Device<->host transfer counters for the observability layer:
@@ -1100,8 +1153,8 @@ class ShardedWindowSweep:
         head = (n_rows // self.n_shards) * self.n_shards
         if head != self._head_rows:
             if self._raw_mirror is None:
-                self._raw_mirror = RoundRobinMirror(self.mesh,
-                                                    self.n_shards)
+                self._raw_mirror = RoundRobinMirror(
+                    self.mesh, self.n_shards, lanes=_LANES)
             self._raw_mirror.append(
                 np.asarray(self.view.source.data[self._head_rows:head],
                            np.float32))

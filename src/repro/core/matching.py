@@ -21,6 +21,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -31,9 +32,11 @@ def euclidean(a, b):
 
 
 def pairwise_euclidean(q, x):
-    """(Q, T) x (N, T) -> (Q, N)."""
+    """(Q, T) x (N, T) -> (Q, N).  The cross term runs at full f32
+    precision: a TPU's default matmul precision is a bf16 pass, too coarse
+    for a ground-truth distance."""
     d2 = (jnp.sum(q * q, -1)[:, None] + jnp.sum(x * x, -1)[None, :]
-          - 2.0 * q @ x.T)
+          - 2.0 * jnp.matmul(q, x.T, precision=jax.lax.Precision.HIGHEST))
     return jnp.sqrt(jnp.maximum(d2, 0.0))
 
 

@@ -16,6 +16,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 from jax.scipy.special import ndtri
 
@@ -85,6 +86,7 @@ class OneDSAX:
     def pairwise_distance(self, rq, rx):
         vq = self.reconstruct(rq)                       # (Q, T)
         vx = self.reconstruct(rx)                       # (N, T)
+        # full f32 cross term (a TPU's default matmul precision is bf16)
         d2 = jnp.sum(vq * vq, -1)[:, None] + jnp.sum(vx * vx, -1)[None, :] \
-            - 2.0 * vq @ vx.T
+            - 2.0 * jnp.matmul(vq, vx.T, precision=jax.lax.Precision.HIGHEST)
         return jnp.sqrt(jnp.maximum(d2, 0.0))

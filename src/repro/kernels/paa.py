@@ -1,30 +1,26 @@
 """Pallas kernel: PAA segmentation front-end (Eq. 5).
 
-(N, T) -> (N, W) segment means.  Memory-bound streaming reduction: grid
-tiles candidates; within a tile the (BLK_N, T) slab is reshaped
-(BLK_N, W, T/W) in VMEM and mean-reduced on the VPU.  For long series the
-T axis is additionally tiled and partial sums accumulate in the output
-block (revisited across the seg-tile grid axis).
+(N, T) -> (N, W) segment means.  Grid tiles candidates; within a tile the
+(BLK_N, T) slab is contracted on the MXU against the (T, W) averaging
+matrix ``A[t, w] = 1/E if t // E == w`` (E = T / W), at f32 contraction
+precision.  Splitting the lane axis into (W, E) segments in VMEM would be
+the direct formulation, but the TPU lowering cannot reshape a lane axis
+into two, so the segment reduction is expressed as a contraction.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BLK_N = 128
-BLK_W = 64          # segments per grid step (bounds VMEM at BLK_N*BLK_W*E)
 
 
-def _kernel(x_ref, out_ref, *, seg_len: int):
-    x = x_ref[...]                                  # (BLK_N, BLK_W*seg_len)
-    n, tw = x.shape
-    w = tw // seg_len
-    out_ref[...] = jnp.mean(
-        x.reshape(n, w, seg_len).astype(jnp.float32), axis=-1)
+def _kernel(x_ref, avg_ref, out_ref):
+    out_ref[...] = jnp.dot(x_ref[...].astype(jnp.float32), avg_ref[...],
+                           precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
 
 
 def paa_pallas(x, n_segments: int, *, interpret: bool = False):
@@ -34,16 +30,15 @@ def paa_pallas(x, n_segments: int, *, interpret: bool = False):
     assert T % W == 0, (T, W)
     E = T // W
     blk_n = min(BLK_N, N)
-    blk_w = min(BLK_W, W)
-    while W % blk_w:                    # largest divisor of W <= BLK_W
-        blk_w -= 1
-    assert N % blk_n == 0 and W % blk_w == 0
-    grid = (N // blk_n, W // blk_w)
+    assert N % blk_n == 0, (N, blk_n)
+    seg = jnp.arange(T)[:, None] // E == jnp.arange(W)[None, :]
+    avg = jnp.where(seg, jnp.float32(1.0 / E), jnp.float32(0.0))
     return pl.pallas_call(
-        functools.partial(_kernel, seg_len=E),
-        grid=grid,
-        in_specs=[pl.BlockSpec((blk_n, blk_w * E), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((blk_n, blk_w), lambda i, j: (i, j)),
+        _kernel,
+        grid=(N // blk_n,),
+        in_specs=[pl.BlockSpec((blk_n, T), lambda i: (i, 0)),
+                  pl.BlockSpec((T, W), lambda i: (0, 0))],
+        out_specs=pl.BlockSpec((blk_n, W), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, W), jnp.float32),
         interpret=interpret,
-    )(x)
+    )(x, avg)

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -62,7 +63,8 @@ def sliding_dot_ref(x, q, stride: int = 1):
     starts = jnp.arange(S) * stride
     idx = starts[:, None] + jnp.arange(m)[None, :]     # (S, m)
     w = x[:, idx]                                      # (N, S, m)
-    return jnp.einsum("nsm,qm->qns", w, q)
+    return jnp.einsum("nsm,qm->qns", w, q,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def windowed_euclid_ref(x, q, stride: int = 1):

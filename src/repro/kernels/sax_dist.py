@@ -13,7 +13,8 @@ tile only (W bytes/candidate at int8), which is the whole point of the
 symbolic representation on TPU (DESIGN.md §3).
 
 Block layout: grid over candidate tiles; symbols tile (BLK_N, W) and the
-full (W, A) table per step.  VMEM budget: BLK_N*W*4 + W*A*4; for the
+full (W, A) table per step; the output block is a (BLK_N, 1) column (the
+TPU lowering has no layout for a 1-D output block).  VMEM budget: BLK_N*W*4 + W*A*4; for the
 paper-max A=1024, W<=96 the table is <= 384 KB.
 """
 
@@ -34,9 +35,8 @@ def _kernel(sym_ref, table_ref, out_ref, *, A: int):
     # one-hot contraction on the MXU: (BLK_N, W, A) x (W, A) -> (BLK_N,)
     onehot = (syms[:, :, None] ==
               jax.lax.broadcasted_iota(jnp.int32, (1, 1, A), 2))
-    acc = jnp.sum(onehot * table[None, :, :], axis=(1, 2),
-                  dtype=jnp.float32)
-    out_ref[...] = acc
+    per_w = jnp.sum(onehot * table[None, :, :], axis=2, dtype=jnp.float32)
+    out_ref[...] = jnp.sum(per_w, axis=1, keepdims=True)      # (BLK_N, 1)
 
 
 def sax_dist_pallas(symbols, query_table, *, interpret: bool = False):
@@ -54,7 +54,7 @@ def sax_dist_pallas(symbols, query_table, *, interpret: bool = False):
             pl.BlockSpec((blk, W), lambda i: (i, 0)),
             pl.BlockSpec((W, A), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((blk,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((N,), jnp.float32),
+        out_specs=pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, 1), jnp.float32),
         interpret=interpret,
-    )(symbols, query_table)
+    )(symbols, query_table)[:, 0]

@@ -8,7 +8,8 @@ Stages per candidate tile (BLK_N):
      accumulate sum of squares over (l, w).
 
 The (L, W) cross never leaves VMEM; HBM traffic per candidate is L + W
-symbol bytes.  This replaces the paper's 4*W*L scalar lookups with
+symbol bytes.  The output block is a (BLK_N, 1) column (the TPU lowering
+has no layout for a 1-D output block).  This replaces the paper's 4*W*L scalar lookups with
 L+W gathers + an L*W fused VPU loop (same math — DESIGN.md §3).
 """
 
@@ -42,7 +43,8 @@ def _kernel(seas_ref, res_ref, t1_ref, t2_ref, u1_ref, u2_ref, out_ref, *,
     cell = jnp.maximum(0.0,
                        jnp.maximum(c1[:, :, None] + d1[:, None, :],
                                    c2[:, :, None] + d2[:, None, :]))
-    out_ref[...] = jnp.sum(cell * cell, axis=(1, 2))
+    per_l = jnp.sum(cell * cell, axis=2)                       # (BLK_N, L)
+    out_ref[...] = jnp.sum(per_l, axis=1, keepdims=True)       # (BLK_N, 1)
 
 
 def ssax_dist_pallas(seas_syms, res_syms, t1, t2, u1, u2, *,
@@ -66,7 +68,7 @@ def ssax_dist_pallas(seas_syms, res_syms, t1, t2, u1, u2, *,
             pl.BlockSpec((W, A_res), lambda i: (0, 0)),
             pl.BlockSpec((W, A_res), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((blk,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((N,), jnp.float32),
+        out_specs=pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, 1), jnp.float32),
         interpret=interpret,
-    )(seas_syms, res_syms, t1, t2, u1, u2)
+    )(seas_syms, res_syms, t1, t2, u1, u2)[:, 0]

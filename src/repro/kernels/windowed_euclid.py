@@ -6,27 +6,31 @@ computes
     d2[qi, n, s] = || znorm(x[n, s*stride : s*stride + m]) - q[qi] ||^2
 
 for every window start ``s`` — the distance profile that subsequence
-matching brute-forces — WITHOUT materializing the N * S windows.  Like
-MASS (Mueen et al.), each window's mean / std come from rolling
-sum / sum-of-squares statistics; unlike MASS we compute the sliding dot
-product directly (an m-step accumulation over the window tile, vectorized
-across ``BLK_N`` rows x ``BLK_S`` window starts on the VPU) instead of an
-FFT, which Pallas does not provide.  Per program instance:
+matching brute-forces — WITHOUT materializing the N * S windows.  Each
+window's mean / std come from its sum and sum of squares (as in MASS,
+Mueen et al.), and the sliding dot product is computed directly, by an
+m-step accumulation, instead of by an FFT, which Pallas does not
+provide.  With window mean mu and std sigma (clamped at ``EPS`` exactly
+like :func:`repro.core.normalize.znormalize`), the distance expands to
 
-* the rolling statistics are O(1) per window: one cumulative sum over the
-  slab and two strided slices give every window's sum and sum-of-squares;
-* with window mean mu and std sigma (clamped at ``EPS`` exactly like
-  :func:`repro.core.normalize.znormalize`), the distance expands to
+    d2 = sum(q^2) + (S2 - m*mu^2)/sigma_c^2 - 2*(dot - mu*sum(q))/sigma_c
 
-      d2 = sum(q^2) + (S2 - m*mu^2)/sigma_c^2 - 2*(dot - mu*sum(q))/sigma_c
+so only the three window reductions are needed.
 
-  so only the three slab reductions are needed.
-
-Grid tiles (queries x row-blocks x window-tiles) like
-``kernels/euclid.py``; ragged N / S pad internally to block multiples and
-the padded rows / window starts are sliced out of the result.  The time
-axis is zero-padded so the last window tile's slab slice stays in bounds
-(padded windows are computed on zeros and discarded).
+TPU layout: the corpus is handed to the kernel transposed, (T, N), so
+corpus rows run along the 128 vector lanes and time along sublanes.  A
+program instance owns ``BLK_N`` rows and ``blk_s`` window starts: it
+copies the time slab its windows cover from HBM into VMEM (one DMA at
+a dynamic offset), then accumulates the three reductions over the m
+window offsets with sublane-strided ref loads (``pl.ds(t, blk_s,
+stride)``) — dynamic and strided slicing happen on refs, never on
+values, which the TPU lowering does not support.  The queries are
+broadcast across the lanes in the wrapper, so each step reads
+``q[qi, t]`` as one (1, BLK_N) row.  Grid: (queries x row-blocks x
+window-tiles); ragged N / S pad to block multiples and the padded rows /
+window starts are sliced out of the result, and the time axis is
+zero-padded so the last tile's slab stays in bounds.  Slabs start and
+end on multiples of 8 time steps, the sublane tile of the DMA.
 """
 
 from __future__ import annotations
@@ -36,9 +40,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-BLK_N = 8          # corpus rows per program (each holds its full row)
-BLK_S = 512        # window starts per program
+BLK_N = 128        # corpus rows per program (the lane width)
+BLK_S = 512        # window starts per program (at most)
+SLAB_ROWS = 8192   # time steps of one VMEM slab (4 MiB) before blk_s shrinks
 
 EPS = 1e-12        # must match repro.core.normalize.znormalize
 
@@ -52,49 +58,52 @@ def n_windows(T: int, m: int, stride: int) -> int:
     return (T - m) // stride + 1
 
 
-def _kernel(x_ref, q_ref, out_ref, *, m: int, stride: int, blk_s: int):
+def _kernel(x_hbm, q_ref, out_ref, slab, sem, *, m: int, stride: int,
+            blk_s: int):
+    i = pl.program_id(1)
     j = pl.program_id(2)
-    x = x_ref[...].astype(jnp.float32)            # (BLK_N, T_pad)
-    q = q_ref[...].astype(jnp.float32)            # (1, m)
-    blk_n = x.shape[0]
-    span = (blk_s - 1) * stride + 1               # strided starts footprint
-    slab_len = span - 1 + m
-    t0 = j * blk_s * stride
-    slab = jax.lax.dynamic_slice(x, (0, t0), (blk_n, slab_len))
+    # a tile start is a multiple of blk_s * stride, and blk_s is a
+    # multiple of 8 whenever there is more than one tile
+    t0 = pl.multiple_of(j * (blk_s * stride), 8)
+    cp = pltpu.make_async_copy(
+        x_hbm.at[pl.ds(t0, slab.shape[0]), pl.ds(i * BLK_N, BLK_N)],
+        slab, sem)
+    cp.start()
+    cp.wait()
 
-    # rolling window sums / sums of squares via one cumulative sum each:
-    # window s covers slab[:, s*stride : s*stride + m]
-    zero = jnp.zeros((blk_n, 1), jnp.float32)
-    cs1 = jnp.concatenate([zero, jnp.cumsum(slab, axis=1)], axis=1)
-    cs2 = jnp.concatenate([zero, jnp.cumsum(slab * slab, axis=1)], axis=1)
-    lo1 = jax.lax.slice(cs1, (0, 0), (blk_n, span), (1, stride))
-    hi1 = jax.lax.slice(cs1, (0, m), (blk_n, m + span), (1, stride))
-    lo2 = jax.lax.slice(cs2, (0, 0), (blk_n, span), (1, stride))
-    hi2 = jax.lax.slice(cs2, (0, m), (blk_n, m + span), (1, stride))
-    s1 = hi1 - lo1                                # (BLK_N, BLK_S)
-    s2 = hi2 - lo2
+    # window s of the tile covers slab rows [s*stride, s*stride + m):
+    # offset t of every window is one strided (blk_s, BLK_N) load
+    def body(t, acc):
+        s1, s2, dot = acc
+        xt = slab[pl.ds(t, blk_s, stride=stride), :]
+        qt = q_ref[0, pl.ds(t, 1), :]                 # (1, BLK_N)
+        return s1 + xt, s2 + xt * xt, dot + qt * xt
 
-    # sliding dot product: m vectorized accumulations over the tile
-    def body(i, acc):
-        xi = jax.lax.dynamic_slice(slab, (0, i), (blk_n, span))
-        qi = jax.lax.dynamic_slice(q, (0, i), (1, 1))
-        return acc + qi * xi[:, ::stride]
+    zero = jnp.zeros((blk_s, BLK_N), jnp.float32)
+    s1, s2, dot = jax.lax.fori_loop(0, m, body, (zero, zero, zero))
 
-    dot = jax.lax.fori_loop(0, m, body,
-                            jnp.zeros((blk_n, blk_s), jnp.float32))
-
+    q = q_ref[0]                                      # (m, BLK_N)
+    q_sum = jnp.sum(q, axis=0, keepdims=True)
+    q_ss = jnp.sum(q * q, axis=0, keepdims=True)
     mu = s1 / m
     var = s2 / m - mu * mu
     sig = jnp.maximum(jnp.sqrt(jnp.maximum(var, 0.0)), EPS)
-    q_sum = jnp.sum(q)
-    q_ss = jnp.sum(q * q)
     norm2 = jnp.maximum(s2 - m * mu * mu, 0.0) / (sig * sig)
     d2 = q_ss + norm2 - 2.0 * (dot - mu * q_sum) / sig
     # a zero-variance window z-normalizes to the zero vector (znormalize's
     # eps guard), so its distance is exactly sum(q^2); the expanded form
     # would divide rounding noise by eps instead
     d2 = jnp.where(var > 0.0, d2, q_ss)
-    out_ref[...] = jnp.maximum(d2, 0.0)[None]     # (1, BLK_N, BLK_S)
+    out_ref[0] = jnp.maximum(d2, 0.0)                 # (blk_s, BLK_N)
+
+
+def _tile_starts(S: int, m: int, stride: int) -> int:
+    """Window starts per program: all of them when they fit one tile,
+    else the largest multiple of 8 (the sublane tile) that is at most
+    ``BLK_S`` and keeps the slab within ``SLAB_ROWS``."""
+    fit = (SLAB_ROWS - m) // stride + 1
+    blk = max(8, min(BLK_S, fit) // 8 * 8)
+    return S if S <= blk else blk
 
 
 def windowed_euclid_pallas(x, q, *, stride: int = 1,
@@ -112,29 +121,29 @@ def windowed_euclid_pallas(x, q, *, stride: int = 1,
     N, T = x.shape
     Q, m = q.shape
     S = n_windows(T, m, stride)
-    blk_n = min(BLK_N, N)
-    blk_s = min(BLK_S, S)
-    pad_n = (-N) % blk_n
-    pad_s = (-S) % blk_s
-    sp = S + pad_s
-    # the last window tile's slab reads up to (sp - 1)*stride + m
-    t_need = (sp - 1) * stride + m
-    pad_t = max(t_need - T, 0)
-    if pad_n or pad_t:
-        x = jnp.pad(x, ((0, pad_n), (0, pad_t)))
-    np_, tp = N + pad_n, T + pad_t
-    grid = (Q, np_ // blk_n, sp // blk_s)
+    blk_s = _tile_starts(S, m, stride)
+    sp = S + (-S) % blk_s
+    np_ = N + (-N) % BLK_N
+    # a slab covers its tile's windows, rounded up to the sublane tile
+    slab_len = (blk_s - 1) * stride + m
+    slab_len += (-slab_len) % 8
+    tp = max((sp - blk_s) * stride + slab_len, T)
+    xt = jnp.pad(x.astype(jnp.float32).T, ((0, tp - T), (0, np_ - N)))
+    qb = jnp.broadcast_to(q.astype(jnp.float32)[:, :, None], (Q, m, BLK_N))
+    grid = (Q, np_ // BLK_N, sp // blk_s)
     out = pl.pallas_call(
         functools.partial(_kernel, m=m, stride=stride, blk_s=blk_s),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((blk_n, tp), lambda qi, i, j: (i, 0)),
-            pl.BlockSpec((1, m), lambda qi, i, j: (qi, 0)),
+            pl.BlockSpec(memory_space=pltpu.HBM),
+            pl.BlockSpec((1, m, BLK_N), lambda qi, i, j: (qi, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, blk_n, blk_s),
-                               lambda qi, i, j: (qi, i, j)),
-        out_shape=jax.ShapeDtypeStruct((Q, np_, sp), jnp.float32),
+        out_specs=pl.BlockSpec((1, blk_s, BLK_N),
+                               lambda qi, i, j: (qi, j, i)),
+        out_shape=jax.ShapeDtypeStruct((Q, sp, np_), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((slab_len, BLK_N), jnp.float32),
+                        pltpu.SemaphoreType.DMA],
         interpret=interpret,
-    )(x, q)
-    out = out[:, :N, :S]
+    )(xt, qb)
+    out = jnp.swapaxes(out, 1, 2)[:, :N, :S]
     return out[0] if squeeze else out

@@ -201,8 +201,6 @@ def dryrun_cell(arch: str, shape_name: str, *, multi_pod,
     t_compile = time.time() - t0
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):     # jax 0.4 returns [dict]
-        cost = cost[0] if cost else {}
     mem = _mem_dict(compiled.memory_analysis())
     colls = collective_bytes(compiled.as_text())
     tot, act = cfg.param_counts()

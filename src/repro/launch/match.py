@@ -355,6 +355,8 @@ def main():
             args.window = min(args.window, 240)
             args.stride = max(args.stride, 8)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.selfjoin:
         args.k = min(args.k, 4)       # motif/discord count, not top-k
         return run_selfjoin(args)
@@ -386,11 +388,12 @@ def main():
     print(f"[match] {args.technique} over {n} x {args.T} "
           f"on {n_dev} devices (verify={args.verify})")
     t0 = time.perf_counter()
-    engine = make_engine_service(tech, jnp.asarray(D), mesh,
+    # the host array goes in as is: the service shards it row-wise onto
+    # the mesh, never through one device
+    engine = make_engine_service(tech, D, mesh,
                                  batch_size=args.batch, media=args.store,
                                  verify=args.verify, metrics=REGISTRY)
     store = engine.store                 # SymbolicStore: raw + live rep
-    jax.block_until_ready(engine.rep)
     print(f"[match] encode: {time.perf_counter() - t0:.2f}s")
 
     ed = np.asarray(pairwise_euclidean(jnp.asarray(Q), jnp.asarray(D)))

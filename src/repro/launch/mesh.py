@@ -4,29 +4,18 @@
 this module never touches jax device state.  Single pod: 16x16 = 256 chips
 ("data", "model").  Multi-pod: 2x16x16 = 512 chips ("pod", "data",
 "model") — the leading axis is the cross-pod (DCN) data-parallel axis.
-
-``jax.sharding.AxisType`` landed after jax 0.4; on older runtimes every
-mesh axis is Auto-typed already, so ``make_mesh_compat`` simply omits the
-argument there instead of crashing at import.
 """
 
 from __future__ import annotations
 
 import jax
-
-try:                                  # jax >= 0.5
-    from jax.sharding import AxisType
-
-    def _axis_types(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:                   # jax 0.4: Auto is the only behavior
-    def _axis_types(n: int) -> dict:
-        return {}
+from jax.sharding import AxisType
 
 
 def make_mesh_compat(shape, axes):
-    """jax.make_mesh with Auto axis types on any supported jax version."""
-    return jax.make_mesh(shape, axes, **_axis_types(len(axes)))
+    """``jax.make_mesh`` with every axis Auto-typed (the sharding mode all
+    of the repo's ``shard_map`` programs are written for)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

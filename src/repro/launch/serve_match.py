@@ -90,14 +90,17 @@ def main():
         args.leaf_fill = min(args.leaf_fill, 16)
 
     import jax
-    import jax.numpy as jnp
 
     from repro.core import make_technique
     from repro.core.distributed import make_engine_service
     from repro.data.synthetic import season_dataset
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_mesh_compat
     from repro.obs import REGISTRY
     from repro.service import MatchSession
+    from repro.service.queue import SHED_ENGINE_ERROR
+
+    enable_compile_cache()
 
     n_dev = len(jax.devices())
     mesh = make_mesh_compat((n_dev,), ("data",))
@@ -115,11 +118,12 @@ def main():
     print(f"[serve] {args.technique} over {n} x {args.T} on {n_dev} "
           f"devices (verify={args.verify})")
     t0 = time.perf_counter()
-    engine = make_engine_service(tech, jnp.asarray(D), mesh,
+    # the host array goes in as is: the service shards it row-wise onto
+    # the mesh, never through one device
+    engine = make_engine_service(tech, D, mesh,
                                  batch_size=args.batch, media=args.store,
                                  verify=args.verify, metrics=REGISTRY)
     engine.store.build_index(leaf_fill=args.leaf_fill)
-    jax.block_until_ready(engine.rep)
     # replicas share the ONE store (dataset=None adopts it); each keeps
     # its own device mirrors, synced independently by store version
     replicas = [make_engine_service(tech, None, mesh,
@@ -206,6 +210,14 @@ def main():
             by_rep[r.replica] = by_rep.get(r.replica, 0) + 1
         print(f"[serve] replica placement: {by_rep}")
 
+    unserved = [r for r in results if r is None or not r.ok]
+    for r in unserved[:4]:
+        print(f"[serve] unserved: "
+              f"{'no answer' if r is None else (r.shed_reason, r.error)}")
+    if unserved:
+        raise SystemExit(f"[serve] {len(unserved)}/{n_q} wave-1 requests "
+                         "were not served")
+
     mism = 0
     for r in ok:
         if r.tier_served == "approx":
@@ -247,6 +259,10 @@ def main():
           f"({sum(1 for b in bars if b == 0)}/{len(bars)} provably exact)")
 
     session.close()
+    failed = [r for r in reqs if r.shed_reason == SHED_ENGINE_ERROR]
+    if failed:
+        raise SystemExit(f"[serve] {len(failed)} wave-2 requests shed as "
+                         f"{SHED_ENGINE_ERROR}: {failed[0].error}")
     from repro.launch.match import _print_metrics
     _print_metrics(REGISTRY)
     print("[serve] planner estimates: "
