@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - (union of the device's operation intervals) / (traced window)."""
+
+
+def read(run):
+    dev = run.device
+    if dev is None or not dev.devices or dev.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - dev.busy_s() / dev.window_s)
