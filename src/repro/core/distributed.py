@@ -93,7 +93,9 @@ def _data_axes(mesh: Mesh):
 # recompile on EVERY sweep (tens of seconds for the richer encoders).
 # The cached callables compile once per input shape and are shared by
 # every engine over the same mesh.  The compiled body is unchanged, so
-# results are unchanged.
+# results are unchanged.  Each body is named for its role, which names
+# its compiled module (``jit_rr_bounds``, ``jit_rr_rows_verify``, ...)
+# on the profiler's timeline.
 
 @lru_cache(maxsize=64)
 def _encode_fn(mesh: Mesh, encoder, out_def, out_ndims):
@@ -102,8 +104,11 @@ def _encode_fn(mesh: Mesh, encoder, out_def, out_ndims):
     # axes replicated
     spec_out = jax.tree.unflatten(
         out_def, [P(axes, *([None] * (nd - 1))) for nd in out_ndims])
+    def encode_rows(x):
+        return encoder.encode(x)
+
     return jax.jit(jax.shard_map(
-        lambda x: encoder.encode(x), mesh=mesh, in_specs=(P(axes, None),),
+        encode_rows, mesh=mesh, in_specs=(P(axes, None),),
         out_specs=spec_out, check_vma=False))
 
 
@@ -165,8 +170,11 @@ def _repr_dists_fn(mesh: Mesh, pw, q_def, x_def, q_ndims, x_ndims):
     in_q = jax.tree.unflatten(q_def, [P(*([None] * nd)) for nd in q_ndims])
     in_x = jax.tree.unflatten(
         x_def, [P(axes, *([None] * (nd - 1))) for nd in x_ndims])
+    def repr_dists(rq, rx):
+        return pw(rq, rx)
+
     return jax.jit(jax.shard_map(
-        lambda rq, rx: pw(rq, rx), mesh=mesh, in_specs=(in_q, in_x),
+        repr_dists, mesh=mesh, in_specs=(in_q, in_x),
         out_specs=P(None, axes), check_vma=False))
 
 
@@ -183,7 +191,7 @@ def repr_distances_sharded(encoder, rep_query, rep_data, mesh: Mesh,
 def _repr_topk_fn(mesh: Mesh, pw, k: int, q_def, x_def, q_ndims, x_ndims):
     axes = _data_axes(mesh)
 
-    def local(rq, rx):
+    def repr_topk(rq, rx):
         d = pw(rq, rx)                                 # (Q, n_local)
         n_local = d.shape[1]
         kk = min(k, n_local)
@@ -199,7 +207,7 @@ def _repr_topk_fn(mesh: Mesh, pw, k: int, q_def, x_def, q_ndims, x_ndims):
     in_x = jax.tree.unflatten(
         x_def, [P(axes, *([None] * (nd - 1))) for nd in x_ndims])
     return jax.jit(jax.shard_map(
-        local, mesh=mesh, in_specs=(in_q, in_x),
+        repr_topk, mesh=mesh, in_specs=(in_q, in_x),
         out_specs=(P(None, None), P(None, None)), check_vma=False))
 
 
@@ -251,12 +259,12 @@ def _rr_place_fn(mesh: Mesh, ndim: int):
     corpus-wide concatenate."""
     spec = _row_spec(mesh, ndim)
 
-    def local(buf, delta, start):
+    def rr_place(buf, delta, start):
         zeros = (0,) * (buf.ndim - 1)
         return jax.lax.dynamic_update_slice(buf, delta, (start,) + zeros)
 
     return jax.jit(jax.shard_map(
-        local, mesh=mesh, in_specs=(spec, spec, P()), out_specs=spec,
+        rr_place, mesh=mesh, in_specs=(spec, spec, P()), out_specs=spec,
         check_vma=False), donate_argnums=0)
 
 
@@ -266,12 +274,12 @@ def _rr_grow_fn(mesh: Mesh, ndim: int, new_cap: int):
     ``new_cap`` slots on device."""
     spec = _row_spec(mesh, ndim)
 
-    def local(buf):
+    def rr_grow(buf):
         pad = [(0, new_cap - buf.shape[0])] + [(0, 0)] * (buf.ndim - 1)
         return jnp.pad(buf, pad)
 
     return jax.jit(jax.shard_map(
-        local, mesh=mesh, in_specs=(spec,), out_specs=spec,
+        rr_grow, mesh=mesh, in_specs=(spec,), out_specs=spec,
         check_vma=False), donate_argnums=0)
 
 
@@ -372,13 +380,13 @@ def _rr_bounds_fn(mesh: Mesh, pw, q_def, x_def, q_ndims, x_ndims):
     in_x = jax.tree.unflatten(
         x_def, [P(axes, *([None] * (nd - 1))) for nd in x_ndims])
 
-    def local(rq, rx, per):
+    def rr_bounds(rq, rx, per):
         d = pw(rq, rx)                                 # (Q, cap)
         dead = jnp.arange(d.shape[1])[None, :] >= per
         return jnp.where(dead, jnp.inf, d)
 
     return jax.jit(jax.shard_map(
-        local, mesh=mesh, in_specs=(in_q, in_x, P()),
+        rr_bounds, mesh=mesh, in_specs=(in_q, in_x, P()),
         out_specs=P(None, axes), check_vma=False))
 
 
@@ -396,7 +404,7 @@ def _rr_topk_fn(mesh: Mesh, pw, k: int, n_shards: int,
     in_x = jax.tree.unflatten(
         x_def, [P(axes, *([None] * (nd - 1))) for nd in x_ndims])
 
-    def local(rq, rx, per):
+    def rr_topk(rq, rx, per):
         d = pw(rq, rx)                                 # (Q, cap)
         cap = d.shape[1]
         d = jnp.where(jnp.arange(cap)[None, :] >= per, jnp.inf, d)
@@ -414,7 +422,7 @@ def _rr_topk_fn(mesh: Mesh, pw, k: int, n_shards: int,
                 jnp.take_along_axis(cand_i, best, axis=1))
 
     return jax.jit(jax.shard_map(
-        local, mesh=mesh, in_specs=(in_q, in_x, P()),
+        rr_topk, mesh=mesh, in_specs=(in_q, in_x, P()),
         out_specs=(P(None, None), P(None, None)), check_vma=False))
 
 
@@ -437,7 +445,7 @@ def _rr_rows_verify_fn(mesh: Mesh, n_shards: int):
     folds repeated (Qa, B, T) round shapes)."""
     axes = _data_axes(mesh)
 
-    def local(x, q, c, per):
+    def rr_rows_verify(x, q, c, per):
         cap = x.shape[0]                              # x: (cap, T_pad) local
         slot = c // n_shards
         valid = ((c >= 0) & (c % n_shards == _shard_index(axes))
@@ -448,7 +456,7 @@ def _rr_rows_verify_fn(mesh: Mesh, n_shards: int):
         return jax.lax.pmin(jnp.where(valid, d2, jnp.inf), axes)
 
     return jax.jit(jax.shard_map(
-        local, mesh=mesh,
+        rr_rows_verify, mesh=mesh,
         in_specs=(P(axes, None), P(None, None), P(None, None), P()),
         out_specs=P(None, None), check_vma=False))
 
@@ -478,7 +486,7 @@ def _rr_windows_gather_fn(mesh: Mesh, n_shards: int, nw: int, stride: int,
     re-assembles the full batch (x + 0 is exact in f32)."""
     axes = _data_axes(mesh)
 
-    def local(x, c, per):
+    def rr_windows_gather(x, c, per):
         cap = x.shape[0]                   # x: (cap, T_src [+ lane pad])
         row = jnp.where(c >= 0, c // nw, -1)
         start = (c % nw) * stride          # in-bounds even for c == -1
@@ -491,7 +499,7 @@ def _rr_windows_gather_fn(mesh: Mesh, n_shards: int, nw: int, stride: int,
         return jax.lax.psum(jnp.where(valid[..., None], w, 0.0), axes)
 
     return jax.jit(jax.shard_map(
-        local, mesh=mesh,
+        rr_windows_gather, mesh=mesh,
         in_specs=(P(axes, None), P(None, None), P()),
         out_specs=P(None, None, None), check_vma=False))
 

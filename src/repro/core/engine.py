@@ -226,13 +226,17 @@ def topk_verify(queries_raw, repr_dists, store: RawStore, *, k: int = 1,
     (bound, id)-sorted, and the result is exact for ANY valid-bound
     order.
 
-    ``trace``: optional ``repro.obs.Trace``.  Every recording site is
-    guarded by ``trace is None`` and records copies after the round's
-    computation — with no trace the loop executes the exact
-    pre-observability instruction stream, and with one the results and
-    store accounting stay bit-identical (property-tested in
-    tests/test_obs_neutrality.py)."""
+    ``trace``: optional ``repro.obs.Trace``.  Each round records its
+    ``peek``, ``take``, ``dist`` and ``merge`` steps as child spans
+    (the closing ``peek`` that finds no active query is one more).
+    Every recording site is guarded by ``trace is None`` (or
+    ``maybe_span``) and records copies after the round's computation —
+    with no trace the loop executes the pre-observability instruction
+    stream plus four null-context entries a round, and with one the
+    results and store accounting stay bit-identical (property-tested
+    in tests/test_obs_neutrality.py)."""
     import time as _time
+    from repro.obs.trace import maybe_span
     qs = np.asarray(queries_raw)        # native dtype: the host verifier
     if qs.ndim == 1:                    # stays bit-identical to brute force
         qs = qs[None]
@@ -312,41 +316,52 @@ def topk_verify(queries_raw, repr_dists, store: RawStore, *, k: int = 1,
         # of a seeded index sweep) out of the scan entirely; a stream
         # peeks +inf past its finite frontier, so the guard doubles as
         # its exhaustion check.
-        if stream is None:
-            nxt = sorted_d[np.arange(q_n), np.minimum(pos, n - 1)]
-            active = (pos < n) & np.isfinite(nxt) & (front_d[:, -1] >= nxt)
-        else:
-            nxt = stream.peek()
-            active = np.isfinite(nxt) & (front_d[:, -1] >= nxt)
+        # each round's four steps are spans when traced: "peek" (next
+        # bounds), "take" (candidate ids), "dist" (true distances) and
+        # "merge" (the best-k frontier); on the device path each ends
+        # in a fetch to the host, so none needs a fence
+        with maybe_span(trace, "peek"):
+            if stream is None:
+                nxt = sorted_d[np.arange(q_n), np.minimum(pos, n - 1)]
+                active = ((pos < n) & np.isfinite(nxt)
+                          & (front_d[:, -1] >= nxt))
+            else:
+                nxt = stream.peek()
+                active = np.isfinite(nxt) & (front_d[:, -1] >= nxt)
         if not active.any():
             break
         aq = np.nonzero(active)[0]
         t_round = _time.perf_counter() if trace is not None else 0.0
-        if stream is None:
-            cand = np.full((len(aq), batch_size), -1, np.int64)
-            for r, qi in enumerate(aq):
-                c = order[qi, pos[qi]:min(pos[qi] + batch_size, n_fin[qi])]
-                cand[r, :len(c)] = c
-            if col_ids is not None:      # column -> dataset row translation
-                cand = np.where(cand >= 0, col_ids[cand], -1)
-        else:                            # global ids straight off device
-            cand = np.asarray(stream.take(aq, batch_size), np.int64)
+        with maybe_span(trace, "take"):
+            if stream is None:
+                cand = np.full((len(aq), batch_size), -1, np.int64)
+                for r, qi in enumerate(aq):
+                    c = order[qi,
+                              pos[qi]:min(pos[qi] + batch_size, n_fin[qi])]
+                    cand[r, :len(c)] = c
+                if col_ids is not None:  # column -> dataset row ids
+                    cand = np.where(cand >= 0, col_ids[cand], -1)
+            else:                        # global ids straight off device
+                cand = np.asarray(stream.take(aq, batch_size), np.int64)
         mask = cand >= 0
-        if dist_fn is not None:          # device-resident: no host fetch
-            d = np.asarray(dist_fn(aq, cand))
-        else:
-            ids = np.unique(cand[mask])          # sorted
-            rows = store.fetch(ids)              # one physical fetch/round
-            gather = np.searchsorted(ids, np.where(mask, cand, ids[0]))
-            d = verifier(rows, qs[aq], gather)
+        with maybe_span(trace, "dist"):
+            if dist_fn is not None:      # device-resident: no host fetch
+                d = np.asarray(dist_fn(aq, cand))
+            else:
+                ids = np.unique(cand[mask])          # sorted
+                rows = store.fetch(ids)              # one physical fetch
+                gather = np.searchsorted(ids, np.where(mask, cand, ids[0]))
+                d = verifier(rows, qs[aq], gather)
         d = np.where(mask, d, np.inf)
         if on_verified is not None:
             for r, qi in enumerate(aq):
                 on_verified(int(qi), cand[r][mask[r]],
                             np.asarray(d[r][mask[r]], np.float64))
 
-        new_d, new_i = merge(np.concatenate([front_d[aq], d], axis=1),
-                             np.concatenate([front_i[aq], cand], axis=1), k)
+        with maybe_span(trace, "merge"):
+            new_d, new_i = merge(
+                np.concatenate([front_d[aq], d], axis=1),
+                np.concatenate([front_i[aq], cand], axis=1), k)
         front_d[aq] = new_d
         front_i[aq] = new_i
         n_real = mask.sum(axis=1)

@@ -307,7 +307,7 @@ def topk_from_source(queries_raw, source: CandidateSource, store, *,
                                      dist_fn=dist_fn,
                                      on_verified=on_verified, trace=trace)
 
-    with maybe_span(trace, "order") as order_span:
+    with maybe_span(trace, "order"):
         cs = source.candidate_bounds(qs, k, verify)
         if trace is not None and cs.stream is not None:
             # the stream's sort ran on device — fence it so the "order"
@@ -315,7 +315,6 @@ def topk_from_source(queries_raw, source: CandidateSource, store, *,
             from repro.obs.trace import block_until_ready
             block_until_ready((getattr(cs.stream, "_b", None),
                                getattr(cs.stream, "_i", None)))
-            order_span.meta["stream"] = True
     with maybe_span(trace, "verify"):
         res = topk_verify(qs, cs.bounds, store, k=k, batch_size=batch_size,
                           verifier=verifier, merge=merge,

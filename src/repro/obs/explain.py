@@ -57,17 +57,34 @@ def render_trace(trace: Trace) -> str:
         head.append("approximate")
     lines.append(f"== {trace.name} ({', '.join(head)}) ==")
 
-    # phase wall-clocks from the span tree (top-level spans only; nested
-    # child time — e.g. order/seed — is included in its parent)
-    tops = [s for s in trace.spans if "/" not in s.name]
-    if tops:
-        phases = " | ".join(f"{s.name} {_fmt_s(s.seconds)}" for s in tops)
-        lines.append(f"phases: {phases}"
-                     + (f"  (total {_fmt_s(m['wall_s'])})"
-                        if "wall_s" in m else ""))
-        nested = [s for s in trace.spans if "/" in s.name]
-        for s in nested:
-            lines.append(f"  .. {s.name} {_fmt_s(s.seconds)}")
+    # phase wall-clocks from the span tree: the top-level spans, or the
+    # children of a served dispatch's "dispatch" root; deeper spans
+    # (order/seed, a round's verify/take, ...) are summed by path, as a
+    # dispatch of many rounds holds hundreds of them.  A child's time
+    # is included in its parent's.
+    root = next((s for s in trace.spans if s.name == "dispatch"), None)
+    pre = "dispatch/" if root is not None else ""
+    phases, nested = {}, {}
+    for s in trace.spans:
+        if s is root or not s.name.startswith(pre):
+            continue
+        path = s.name[len(pre):]
+        agg = nested if "/" in path else phases
+        c, t = agg.get(path, (0, 0.0))
+        agg[path] = (c + 1, t + s.seconds)
+
+    def _fmt(name, c, t):
+        return f"{name} {_fmt_s(t)}" + (f" x{c}" if c > 1 else "")
+
+    if phases:
+        tail = [f"total {_fmt_s(m['wall_s'])}"] if "wall_s" in m else []
+        if root is not None:
+            tail.append(f"dispatch {_fmt_s(root.seconds)}")
+        lines.append("phases: "
+                     + " | ".join(_fmt(n, *v) for n, v in phases.items())
+                     + (f"  ({', '.join(tail)})" if tail else ""))
+        for n, v in nested.items():
+            lines.append(f"  .. {_fmt(n, *v)}")
 
     gen = _arr(trace, "generated", q_n)
     exa = _arr(trace, "examined", q_n)

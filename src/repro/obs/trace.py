@@ -6,10 +6,14 @@ A ``Trace`` is created per engine call (``MatchEngine.topk(trace=...)``
 * **Spans** — wall-clocked phases.  ``with trace.span("verify"):``
   records a ``Span`` whose name is the '/'-joined path of the open span
   stack (``"order/seed"`` for the tree seed verification nested inside
-  candidate generation).  When the traced region ends in device work,
-  pass ``fence=arrays`` so the span blocks on ``jax.block_until_ready``
-  before closing — kernel timings are then honest rather than dispatch
-  timings.  Fencing only runs when a trace is active, and only *after*
+  candidate generation; ``"dispatch/verify/take"`` for one round's id
+  fetch of a served dispatch).  Each span also opens a
+  ``jax.profiler.TraceAnnotation`` named ``"repro/<path>"``, so under a
+  profiler the program's spans appear on the host line of the thread
+  that ran them, on the device trace's clock.  When the traced region
+  ends in device work, pass ``fence=arrays`` so the span blocks on
+  ``jax.block_until_ready`` before closing — kernel timings are then
+  honest rather than dispatch timings.  Fencing only runs when a trace is active, and only *after*
   the traced computation, so it can never change results or store
   accounting (observability neutrality).
 * **Rounds** — one dict per verification round
@@ -33,11 +37,13 @@ A ``Trace`` is created per engine call (``MatchEngine.topk(trace=...)``
 Zero-overhead-when-off contract: every instrumentation site in the
 matching stack is guarded by ``trace is None`` (or uses
 :func:`maybe_span`, which returns a shared null context) — with no
-trace the hot loops execute exactly the pre-observability instruction
-stream.
+trace the hot loops execute the pre-observability instruction stream
+plus, at a ``maybe_span`` site, the entry and exit of that null
+context.
 
-``to_dict()`` is plain JSON (numpy converted), schema documented in
-ROADMAP.md §Observability.
+``to_dict()`` is plain JSON (numpy converted): ``name``, ``meta``,
+``spans`` (each ``name``, ``seconds`` and, if set, ``meta``) and
+``rounds``.
 """
 
 from __future__ import annotations
@@ -104,19 +110,22 @@ class Trace:
     # -- spans ------------------------------------------------------------
     @contextmanager
     def span(self, name: str, *, fence=None, **meta):
-        """Wall-clock a phase.  ``fence``: device array(s) (or a pytree)
-        to ``block_until_ready`` before the span closes."""
+        """Wall-clock a phase, and annotate it as ``repro/<path>`` on
+        the profiler's host timeline.  ``fence``: device array(s) (or a
+        pytree) to ``block_until_ready`` before the span closes."""
+        from jax.profiler import TraceAnnotation
         path = "/".join(self._stack + [name])
-        sp = Span(path, time.perf_counter(), meta or None)
-        self.spans.append(sp)
-        self._stack.append(name)
-        try:
-            yield sp
-        finally:
-            self._stack.pop()
-            if fence is not None:
-                block_until_ready(fence)
-            sp.t1 = time.perf_counter()
+        with TraceAnnotation(f"repro/{path}"):
+            sp = Span(path, time.perf_counter(), meta or None)
+            self.spans.append(sp)
+            self._stack.append(name)
+            try:
+                yield sp
+            finally:
+                self._stack.pop()
+                if fence is not None:
+                    block_until_ready(fence)
+                sp.t1 = time.perf_counter()
 
     def span_names(self) -> List[str]:
         return [s.name for s in self.spans]

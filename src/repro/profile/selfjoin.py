@@ -384,13 +384,12 @@ class SelfJoinEngine:
         to +inf ON DEVICE before the (bound, id) lexsort — candidate
         order never touches the host."""
         from repro.obs.trace import maybe_span
-        with maybe_span(trace, "order") as sp:
+        with maybe_span(trace, "order"):
             stream = self._sweep.candidate_stream(
                 zq, mask_fn=self._mask_fn(wids))
             if trace is not None:
                 from repro.obs.trace import block_until_ready
                 block_until_ready((stream._b, stream._i))
-                sp.meta["stream"] = True
         with maybe_span(trace, "verify"):
             return topk_verify(zq, None, self.view, k=1, batch_size=bs,
                                verifier=self.verifier, merge=self.merge,

@@ -96,6 +96,7 @@ class MatchRequest:
 
     rid: int = field(default_factory=lambda: next(_RID))
     t_submit: float = 0.0
+    t_dispatch: float = 0.0             # taken off the queue by a dispatch
     t_deadline: Optional[float] = None
     t_done: float = 0.0
     epoch: Optional[object] = None      # corpus frontier pinned at

@@ -51,6 +51,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from repro.obs.trace import maybe_span
 from repro.service.planner import TIERS, QueryPlanner
 from repro.service.queue import (SHED_DEADLINE, CoalescingQueue,
                                  MatchRequest)
@@ -327,6 +328,7 @@ class MatchSession:
         groups: dict = {}
         selfjoin: List[MatchRequest] = []
         for req in batch:
+            req.t_dispatch = now
             if req.t_deadline is not None and now >= req.t_deadline:
                 self.queue.shed(req, SHED_DEADLINE,
                                 "deadline expired while queued")
@@ -396,41 +398,47 @@ class MatchSession:
         epoch = reqs[0].epoch           # group key pins one frontier
         qs = self._bucket(np.stack([r.query for r in reqs])
                           .astype(np.float32))
-        trace = None
+        trace = rids = None
         if any(r.explain for r in reqs):
             from repro.obs import Trace
             trace = Trace("serve.dispatch")
-        t0 = time.perf_counter()
-        res = self._run_tier(qs, k, tier, trace, epoch=epoch,
-                             replica=replica)
-        wall = time.perf_counter() - t0
-        with self._plan_lock:
-            self.planner.observe(tier, qs.shape[0], wall, res)
-            if len(self.engines) > 1:
-                self.planner.observe_replica(replica, wall)
-        ids = getattr(res, "window_ids", None)
-        if ids is None:
-            ids = res.indices
-        kth_lb = getattr(res, "kth_lb", None)
-        error_bar = getattr(res, "error_bar", None)
-        for i, req in enumerate(reqs):
-            req.indices = np.asarray(ids[i]).copy()
-            req.distances = np.asarray(res.distances[i]).copy()
-            if self._subseq:
-                req.rows = np.asarray(res.rows[i]).copy()
-                req.starts = np.asarray(res.starts[i]).copy()
-            if kth_lb is not None:
-                req.kth_lb = float(np.atleast_1d(kth_lb)[i])
-            if error_bar is not None:
-                req.error_bar = float(np.atleast_1d(error_bar)[i])
-            req.tier_served = tier
-            req.replica = replica
-            req.trace = trace
-            req.t_done = time.monotonic()
-            if self.metrics is not None:
-                self.metrics.histogram(
-                    "serve.request_latency_s").observe(req.latency_s)
-                self.metrics.counter(f"serve.tier.{tier}").inc()
+            rids = [r.rid for r in reqs]
+        # traced, the whole dispatch is the root span: the engine's
+        # "order" and "verify" nest under it, and it closes once every
+        # answer is scattered, before any caller is woken
+        with maybe_span(trace, "dispatch", rids=rids):
+            t0 = time.perf_counter()
+            res = self._run_tier(qs, k, tier, trace, epoch=epoch,
+                                 replica=replica)
+            wall = time.perf_counter() - t0
+            with self._plan_lock:
+                self.planner.observe(tier, qs.shape[0], wall, res)
+                if len(self.engines) > 1:
+                    self.planner.observe_replica(replica, wall)
+            ids = getattr(res, "window_ids", None)
+            if ids is None:
+                ids = res.indices
+            kth_lb = getattr(res, "kth_lb", None)
+            error_bar = getattr(res, "error_bar", None)
+            for i, req in enumerate(reqs):
+                req.indices = np.asarray(ids[i]).copy()
+                req.distances = np.asarray(res.distances[i]).copy()
+                if self._subseq:
+                    req.rows = np.asarray(res.rows[i]).copy()
+                    req.starts = np.asarray(res.starts[i]).copy()
+                if kth_lb is not None:
+                    req.kth_lb = float(np.atleast_1d(kth_lb)[i])
+                if error_bar is not None:
+                    req.error_bar = float(np.atleast_1d(error_bar)[i])
+                req.tier_served = tier
+                req.replica = replica
+                req.trace = trace
+                req.t_done = time.monotonic()
+                if self.metrics is not None:
+                    self.metrics.histogram(
+                        "serve.request_latency_s").observe(req.latency_s)
+                    self.metrics.counter(f"serve.tier.{tier}").inc()
+        for req in reqs:
             req.done.set()
 
     def _run_selfjoin(self, reqs: Sequence[MatchRequest],

@@ -312,7 +312,7 @@ class SubseqEngine:
             # device-ordered candidate stream: the (Q, n_windows) bound
             # matrix never materializes on host — the suppression loop
             # below masks host columns, so it keeps the matrix path
-            with maybe_span(trace, "order") as sp:
+            with maybe_span(trace, "order"):
                 mask_fn = None
                 if n_e is not None:
                     # windows past the pinned frontier -> +inf on device
@@ -322,7 +322,6 @@ class SubseqEngine:
                 if trace is not None:
                     from repro.obs.trace import block_until_ready
                     block_until_ready((stream._b, stream._i))
-                    sp.meta["stream"] = True
             with maybe_span(trace, "verify"):
                 res = topk_verify(zq, None, self.view, k=k, batch_size=bs,
                                   verifier=self.verifier, merge=self.merge,

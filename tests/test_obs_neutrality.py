@@ -158,3 +158,50 @@ def test_metrics_registry_is_neutral_too():
     assert snap["counters"]["match.queries"] == n_q
     assert snap["counters"]["match.rows_fetched"] == base["accesses"]
     assert snap["histograms"]["match.topk_latency_s"]["count"] == 1
+
+
+@pytest.mark.parametrize("verify", ["host", "device"])
+def test_session_answers_neutral_to_explain(verify):
+    """Requests served through ``MatchSession`` get bit-identical answers
+    and store accounting with ``explain`` on and off: the dispatch span
+    tree, its per-round spans and their profiler annotations only
+    observe."""
+    from repro.service import MatchSession
+    T, n, n_q, k = 240, 64, 5, 4
+    X = season_dataset(n + n_q, T, L, 0.7, per_series_strength=True,
+                       seed=13)
+    Q, D = X[:n_q], X[n_q:]
+    if verify == "host":
+        store = SymbolicStore.from_rows(_enc("ssax", T), D, media="ssd")
+        store.build_index(leaf_fill=16)
+        engine = MatchEngine(_enc("ssax", T), store, verify="host",
+                             batch_size=32)
+    else:
+        import jax.numpy as jnp
+        from repro.core.distributed import make_engine_service
+        engine = make_engine_service(_enc("ssax", T), jnp.asarray(D),
+                                     _mesh1(), batch_size=32,
+                                     verify="device")
+        engine.store.build_index(leaf_fill=16)
+
+    def serve(tier, explain):
+        engine.store.reset()
+        sess = MatchSession(engine, window_s=0.05, max_batch=8)
+        reqs = [sess.submit(q, k=k, tier=tier, explain=explain)
+                for q in Q]
+        sess.start()
+        for r in reqs:
+            assert r.wait(120) and r.ok, r.error
+        sess.close()
+        return reqs, (engine.store.accesses, engine.store.fetches)
+
+    for tier in ("linear", "index"):
+        base, acc = serve(tier, False)
+        traced, acc_t = serve(tier, True)
+        assert acc == acc_t, tier
+        for a, b in zip(base, traced):
+            assert a.trace is None and b.trace is not None
+            assert np.array_equal(a.indices, b.indices), tier
+            assert np.array_equal(a.distances, b.distances), tier
+            assert a.tier_served == b.tier_served == tier
+        _check(traced[0].trace, device=(verify == "device"))
