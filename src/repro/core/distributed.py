@@ -65,7 +65,10 @@ reduction — identical math to the host ``verify="host"`` fallback
 the host ``verify="numpy"`` path stays the brute-force oracle with
 modeled I/O.  Tail rows are distanced host-side through the same
 kernel — they are already host-resident, so the device path still
-moves zero raw rows device->host.
+moves zero raw rows device->host.  With no tail, a whole stream's
+rounds run as one device program (:func:`verify_stream_rr`): peek,
+take, distances, square root and merge in a ``lax.while_loop``, one
+launch and one fetch per call.
 
 The helpers take any encoder with ``encode`` + ``pairwise_distance`` —
 SAX, sSAX, tSAX and 1d-SAX all plug in.
@@ -461,6 +464,55 @@ def _rr_rows_verify_fn(mesh: Mesh, n_shards: int):
         out_specs=P(None, None), check_vma=False))
 
 
+def _sqrt_rn(x, guess=None):
+    """f32 square root rounded to nearest, as IEEE 754 (and numpy on the
+    host) defines it, whatever the backend's own ``sqrt`` rounds to (a
+    v5e's is up to 3 ulps off).
+
+    ``guess`` (default ``jnp.sqrt(x)``) need only lie within ``_ULPS``
+    ulps of the answer: the answer is the largest float around it whose
+    lower rounding midpoint ``m`` has ``m * m < x`` (a square root never
+    falls on a midpoint), so it is the lowest candidate plus the count of
+    candidates above it that pass.  The test runs on the integer
+    significands, ``X * 2**sh > N * N`` in two 32-bit words, so it is
+    exact on any backend.  Zero, subnormals (XLA flushes them, so the
+    kernel's squared distances hold none), +inf and NaN pass through
+    ``jnp.sqrt``."""
+    u32 = jnp.uint32
+    s = jnp.sqrt(x) if guess is None else guess
+    xb = jax.lax.bitcast_convert_type(x, u32)
+    ex = ((xb >> 23) & 0xFF).astype(jnp.int32)
+    big_x = (xb & 0x7FFFFF) | 0x800000
+
+    def below(cb):
+        """x > (the midpoint below c) ** 2, for a positive normal c with
+        bits ``cb``."""
+        cb = cb.astype(u32)
+        ec = ((cb >> 23) & 0xFF).astype(jnp.int32)
+        mc = (cb & 0x7FFFFF) | 0x800000
+        bottom = (cb & 0x7FFFFF) == 0      # c_prev is half an ulp away
+        n = jnp.where(bottom, 4 * mc - 1, 2 * mc - 1)        # < 2**25
+        sh = (ex - 150 - 2 * jnp.where(bottom, ec - 152, ec - 151)
+              ).astype(u32)                                   # 23..30
+        x_lo, x_hi = big_x << sh, big_x >> (32 - sh)
+        a, b = n >> 16, n & 0xFFFF
+        m = 2 * a * b
+        lo = b * b + ((m & 0xFFFF) << 16)
+        hi = a * a + (m >> 16) + (lo < b * b).astype(u32)
+        return ((x_hi > hi) | ((x_hi == hi) & (x_lo > lo))).astype(
+            jnp.int32)
+
+    sb = jax.lax.bitcast_convert_type(s, jnp.int32) - _ULPS
+    r = sb + sum(below(sb + j) for j in range(1, 2 * _ULPS + 1))
+    normal = (x >= np.finfo(np.float32).tiny) & jnp.isfinite(x)
+    return jnp.where(normal, jax.lax.bitcast_convert_type(r, jnp.float32),
+                     jnp.sqrt(x))
+
+
+#: how far (in ulps) :func:`_sqrt_rn`'s guess may lie from the answer
+_ULPS = 8
+
+
 def cand_dists_rows_rr(raw_buf, q_dev, cand, mesh: Mesh, n_shards: int,
                        per_live: int) -> np.ndarray:
     """True d_ED of candidate ROW ids against a round-robin raw mirror.
@@ -475,6 +527,112 @@ def cand_dists_rows_rr(raw_buf, q_dev, cand, mesh: Mesh, n_shards: int,
     d2 = np.asarray(_rr_rows_verify_fn(mesh, int(n_shards))(
         raw_buf, q_dev, jnp.asarray(cand), jnp.int32(per_live)))
     return np.sqrt(np.maximum(d2, 0.0))
+
+
+@lru_cache(maxsize=64)
+def _rr_verify_loop_fn(mesh: Mesh, n_shards: int, k: int, batch: int,
+                       n_rounds: int):
+    """Jitted sharded verification of a whole device-ordered stream: the
+    round loop of ``core.engine.topk_verify`` as one ``lax.while_loop``,
+    each round its peek, take, row verification (as
+    :func:`_rr_rows_verify_fn`), square root (:func:`_sqrt_rn`, numpy's
+    rounding) and (distance, id) lexsort merge, with the host loop's
+    comparisons in the same order.  The carry is the cursors, the (Q, k)
+    frontier, the round counter and three per-round records
+    (``n_rounds`` long): active queries, rows examined and each query's
+    k-th best after the merge (NaN where inactive).  The mirror is read
+    in place; temporaries are about one (Q, batch) row gather.  Named
+    ``rr_rows_verify``: in the benchmark's terms it is the row-verify
+    program, whatever else its rounds do."""
+    axes = _data_axes(mesh)
+    big = jnp.iinfo(jnp.int32).max
+
+    def rr_rows_verify(x, q, sb, si, n_fin, pos, front_d, front_i, per):
+        cap = x.shape[0]                              # x: (cap, T_pad) local
+        last = sb.shape[1] - 1
+        sid = _shard_index(axes)
+        cols = jnp.arange(batch, dtype=jnp.int32)[None, :]
+
+        def peek(pos, fd):
+            nxt = jnp.take_along_axis(sb, jnp.minimum(pos, last)[:, None],
+                                      axis=1)[:, 0]
+            nxt = jnp.where(pos < n_fin, nxt, jnp.inf)
+            # >= (not >): see topk_verify
+            return jnp.isfinite(nxt) & (fd[:, -1] >= nxt)
+
+        def body(c):
+            r, pos, fd, fi, active, n_act, n_exa, kth = c
+            at = pos[:, None] + cols
+            real = active[:, None] & (at < n_fin[:, None])
+            cand = jnp.where(real, jnp.take_along_axis(
+                si, jnp.minimum(at, last), axis=1), -1)
+            slot = cand // n_shards
+            valid = real & (cand % n_shards == sid) & (slot < per)
+            rows = x[jnp.clip(slot, 0, cap - 1), :q.shape[-1]]
+            d2 = jax.lax.pmin(jnp.where(valid, _kernel_cand_d2(rows, q),
+                                        jnp.inf), axes)
+            d = jnp.where(real, _sqrt_rn(jnp.maximum(d2, 0.0)), jnp.inf)
+            all_d = jnp.concatenate([fd, d], axis=1)
+            all_i = jnp.concatenate([fi, cand], axis=1)
+            sel = jnp.lexsort((jnp.where(all_i < 0, big, all_i), all_d),
+                              axis=-1)[:, :k]
+            keep = active[:, None]
+            fd = jnp.where(keep, jnp.take_along_axis(all_d, sel, axis=1), fd)
+            fi = jnp.where(keep, jnp.take_along_axis(all_i, sel, axis=1), fi)
+            n_real = real.sum(axis=1, dtype=jnp.int32)
+            pos = pos + n_real
+            n_act = n_act.at[r].set(active.sum(dtype=jnp.int32))
+            n_exa = n_exa.at[r].set(n_real.sum())
+            kth = kth.at[r].set(jnp.where(active, fd[:, -1], jnp.nan))
+            return r + 1, pos, fd, fi, peek(pos, fd), n_act, n_exa, kth
+
+        q_n = sb.shape[0]
+        zeros = jnp.zeros(n_rounds, jnp.int32)
+        init = (jnp.int32(0), pos, front_d, front_i, peek(pos, front_d),
+                zeros, zeros, jnp.full((n_rounds, q_n), jnp.nan, jnp.float32))
+        r, pos, fd, fi, _, n_act, n_exa, kth = jax.lax.while_loop(
+            lambda c: c[4].any(), body, init)
+        return r, pos, fd, fi, n_act, n_exa, kth
+
+    rep = P()
+    return jax.jit(jax.shard_map(
+        rr_rows_verify, mesh=mesh,
+        in_specs=(P(axes, None),) + (rep,) * 8,
+        out_specs=(rep,) * 7, check_vma=False))
+
+
+def verify_stream_rr(raw_buf, q_dev, stream, front_d, front_i, mesh: Mesh,
+                     n_shards: int, per_live: int, batch: int, *,
+                     rounds: bool = False):
+    """Every verification round of ``stream`` (a non-empty
+    :class:`DeviceOrderedStream` of ids in the mirrored head) in one
+    device program (:func:`_rr_verify_loop_fn`): one launch, one fetch.
+    Returns ``(front_d, front_i, taken, n_rounds, per_round)``: the final
+    (Q, k) frontier (float64 / int64 on the host, as the host loop keeps
+    it), the (Q,) ids each query verified, the round count, and — only
+    with ``rounds=True``, which fetches the per-round records — a list of
+    ``(active, examined, kth of the active queries)`` per round.  The
+    stream's cursors advance past everything verified, as ``take``
+    advances them."""
+    pos0 = stream._pos
+    k = front_d.shape[1]
+    n_rounds = -(-stream._C // batch) + 1
+    fn = _rr_verify_loop_fn(mesh, int(n_shards), int(k), int(batch),
+                            int(n_rounds))
+    out = fn(raw_buf, q_dev, stream._b, stream._i,
+             stream._n_fin.astype(np.int32), pos0.astype(np.int32),
+             np.asarray(front_d, np.float32), np.asarray(front_i, np.int32),
+             np.int32(per_live))
+    r, pos, fd, fi = jax.device_get(out[:4])
+    r = int(r)
+    stream._pos = pos.astype(np.int64)
+    per_round = None
+    if rounds:
+        n_act, n_exa, kth = jax.device_get(out[4:])
+        per_round = [(int(n_act[i]), int(n_exa[i]),
+                      kth[i][~np.isnan(kth[i])]) for i in range(r)]
+    return (fd.astype(np.float64), fi.astype(np.int64),
+            stream._pos - pos0, r, per_round)
 
 
 @lru_cache(maxsize=64)
@@ -977,7 +1135,13 @@ class ShardedRepSweep:
         computed per shard through the multi-query euclid kernel over
         the round-robin raw mirror — raw rows never move device->host.
         The contract matches ``core.engine.topk_verify``'s
-        ``dist_fn``."""
+        ``dist_fn``.
+
+        When every row is in the mirrored head (no host tail), the
+        closure also carries ``verify_loop(stream, front_d, front_i,
+        batch, rounds=False)`` (:func:`verify_stream_rr`): the whole
+        round loop over a device-ordered stream as one device program,
+        which ``topk_verify`` runs instead of its host loop."""
         if not self.mirror_raw:
             raise ValueError("ShardedRepSweep was built without "
                              "mirror_raw=True; no raw device mirror to "
@@ -1010,6 +1174,14 @@ class ShardedRepSweep:
                     self.store.data[head:n_syn], head, qs, full))
             return out[aq]
 
+        if self._raw_mirror is not None and n_syn == head:
+            def verify_loop(stream, front_d, front_i, batch, rounds=False):
+                return verify_stream_rr(
+                    self._raw_mirror.buf, q_dev, stream, front_d, front_i,
+                    self.mesh, self.n_shards, self._raw_mirror.per_live,
+                    batch, rounds=rounds)
+
+            dist.verify_loop = verify_loop
         return dist
 
 

@@ -49,15 +49,25 @@ generalizes this to top-k and to fixed-size batches:
   exactly; batching only over-fetches by at most one batch per query
   (the batch in flight when the threshold crossed).
 
-Verification itself is batched on device: the surviving candidate rows
-of *all* active queries are fetched from the store in one call (one
-modeled seek per round instead of one per row) and distanced via the
-Pallas kernel ``kernels.euclid.euclid_pallas`` — natively on TPU,
-``interpret=True`` elsewhere.  The frontier merge uses
-``jax.lax.top_k`` on device and a numpy lexicographic sort
-(distance, index) on host; the host path is bit-identical to a numpy
-brute-force scan because each row's distance is reduced over the same T
-values in the same order regardless of batch shape.
+Two loops run the rounds, with the same comparisons in the same order:
+
+* The **host loop** of :func:`topk_verify`: per round, the surviving
+  candidate rows of *all* active queries are fetched from the store in
+  one call (one modeled seek per round instead of one per row) and
+  distanced via the Pallas kernel ``kernels.euclid.euclid_pallas`` —
+  natively on TPU, ``interpret=True`` elsewhere — or handed to a
+  device-resident ``dist_fn``; the frontier merge is a lexicographic
+  sort on (distance, index), in numpy or on device.  The numpy path is
+  bit-identical to a numpy brute-force scan because each row's distance
+  is reduced over the same T values in the same order regardless of
+  batch shape.
+* The **device loop** (``core.distributed.verify_stream_rr``): when the
+  candidates come from a device-ordered stream and are verified against
+  the raw device mirror with no host tail, every round — peek, take,
+  row distances, square root, merge — runs inside one device program
+  (a ``lax.while_loop``): one launch and one fetch per call instead of
+  several host round trips per round.  It answers, and schedules its
+  rounds, exactly as the host loop over the same stream does.
 """
 
 from __future__ import annotations
@@ -86,6 +96,7 @@ class TopKResult:
     store_accesses: int          # deduplicated physical row reads
     store_fetches: int           # batched fetch() calls (modeled seeks)
     io_seconds: float            # batch-accounted modeled I/O
+    device_loop: bool = False    # the rounds ran as one device program
 
 
 # ---------------------------------------------------------------------------
@@ -226,13 +237,27 @@ def topk_verify(queries_raw, repr_dists, store: RawStore, *, k: int = 1,
     (bound, id)-sorted, and the result is exact for ANY valid-bound
     order.
 
-    ``trace``: optional ``repro.obs.Trace``.  Each round records its
-    ``peek``, ``take``, ``dist`` and ``merge`` steps as child spans
-    (the closing ``peek`` that finds no active query is one more).
+    Which loop runs the rounds (module docstring): the device loop when
+    a ``stream`` is given, ``dist_fn`` carries ``verify_loop`` (the
+    sharded sweep's, with every row in the mirrored head) and there is
+    no ``on_verified``; the host loop otherwise — matrix sources, host
+    verifiers, window verification, a host tail, exclusion widening.
+    Both give the same frontier, accesses and rounds (tested in
+    tests/test_device_loop.py); ``TopKResult.device_loop`` says which
+    ran.
+
+    ``trace``: optional ``repro.obs.Trace``.  On the host loop each
+    round records its ``peek``, ``take``, ``dist`` and ``merge`` steps
+    as child spans (the closing ``peek`` that finds no active query is
+    one more); on the device loop one ``loop`` span covers every round
+    up to the fetched result, and each round is recorded afterwards
+    from the program's per-round records (its ``wall_s`` an even share
+    of the span).  The counter ``device_loop`` says which loop ran.
     Every recording site is guarded by ``trace is None`` (or
     ``maybe_span``) and records copies after the round's computation —
-    with no trace the loop executes the pre-observability instruction
-    stream plus four null-context entries a round, and with one the
+    with no trace the host loop executes the pre-observability
+    instruction stream plus four null-context entries a round, the
+    device loop makes no per-round host call at all, and with one the
     results and store accounting stay bit-identical (property-tested
     in tests/test_obs_neutrality.py)."""
     import time as _time
@@ -284,6 +309,10 @@ def topk_verify(queries_raw, repr_dists, store: RawStore, *, k: int = 1,
     pos = np.zeros(q_n, np.int64)
     acc = np.zeros(q_n, np.int64)
     start_acc, start_fetch = store.accesses, store.fetches
+    # the device loop runs every round of a device-ordered stream in one
+    # program; the host loop below serves everything else
+    loop = (getattr(dist_fn, "verify_loop", None)
+            if stream is not None and on_verified is None else None)
     if trace is not None:                # candidates handed to this scan
         if stream is None:
             gen = n_fin.astype(np.int64)
@@ -307,8 +336,22 @@ def topk_verify(queries_raw, repr_dists, store: RawStore, *, k: int = 1,
             if note is not None:
                 note("generated", gen)
         trace.add("generated", gen)
+        trace.add("device_loop", int(loop is not None))
 
-    while True:
+    if loop is not None:
+        t_loop = _time.perf_counter() if trace is not None else 0.0
+        with maybe_span(trace, "loop"):         # ends in the fetch
+            front_d, front_i, acc, n_rounds, per_round = loop(
+                stream, front_d, front_i, batch_size,
+                rounds=trace is not None)
+        if trace is not None:                   # the loop's time, shared
+            wall = (_time.perf_counter() - t_loop) / max(n_rounds, 1)
+            for active, examined, kth in per_round:
+                trace.record_round(phase="scan", active=active,
+                                   examined=examined,
+                                   kth=kth.astype(np.float64), wall_s=wall)
+
+    while loop is None:
         # >= (not >): a candidate whose bound ties the k-th best verified
         # distance may tie it in true distance too and then win on the
         # smaller dataset index — it must be verified, not pruned.  The
@@ -387,7 +430,7 @@ def topk_verify(queries_raw, repr_dists, store: RawStore, *, k: int = 1,
                       raw_accesses=acc,
                       pruned_fraction=1.0 - acc / n,
                       store_accesses=total, store_fetches=n_fetch,
-                      io_seconds=io_s)
+                      io_seconds=io_s, device_loop=loop is not None)
 
 
 def verify_candidates(queries_raw, cand_idx, store: RawStore, *,

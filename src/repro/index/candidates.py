@@ -333,7 +333,7 @@ def topk_from_source(queries_raw, source: CandidateSource, store, *,
                 pruned_fraction=1.0 - res.raw_accesses / n,
                 store_accesses=res.store_accesses,
                 store_fetches=res.store_fetches,
-                io_seconds=res.io_seconds)
+                io_seconds=res.io_seconds, device_loop=res.device_loop)
     else:
         seed = cs.seed_res
         acc = res.raw_accesses + seed.raw_accesses
@@ -343,7 +343,8 @@ def topk_from_source(queries_raw, source: CandidateSource, store, *,
             pruned_fraction=1.0 - acc / max(n, 1),
             store_accesses=res.store_accesses + seed.store_accesses,
             store_fetches=res.store_fetches + seed.store_fetches,
-            io_seconds=res.io_seconds + seed.io_seconds)
+            io_seconds=res.io_seconds + seed.io_seconds,
+            device_loop=res.device_loop)
     if cs.approx_dropped is not None:
         _attach_error_bar(res, cs.approx_dropped, k, trace)
     return res

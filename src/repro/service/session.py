@@ -415,6 +415,11 @@ class MatchSession:
                 self.planner.observe(tier, qs.shape[0], wall, res)
                 if len(self.engines) > 1:
                     self.planner.observe_replica(replica, wall)
+            if self.metrics is not None and getattr(res, "device_loop",
+                                                    False):
+                # dispatches whose verification rounds ran as one
+                # device program (core.engine.topk_verify)
+                self.metrics.counter("serve.device_loop").inc()
             ids = getattr(res, "window_ids", None)
             if ids is None:
                 ids = res.indices

@@ -3,8 +3,9 @@ annotations and the names of the programs it runs.
 
 A traced ``MatchSession`` dispatch records the root span ``dispatch``
 (meta ``rids``: the requests it answers), the engine's ``order`` and
-``verify`` under it, and under ``verify`` each verification round's
-``peek``, ``take``, ``dist`` and ``merge``.  Each span is also a
+``verify`` under it, and under ``verify`` either each host round's
+``peek``, ``take``, ``dist`` and ``merge``, or, where the rounds run as
+one device program, the one span ``loop``.  Each span is also a
 ``jax.profiler.TraceAnnotation`` named ``repro/<path>``, which a device
 trace keeps on the dispatching thread's host line.  Every request
 carries the time its dispatch took it off the queue (``t_dispatch``).
@@ -77,12 +78,20 @@ def test_dispatch_span_tree(verify):
     assert rounds >= 1
     assert names["dispatch"] == names["dispatch/order"] \
         == names["dispatch/verify"] == 1
-    for step in ("take", "dist", "merge"):
-        assert names[f"dispatch/verify/{step}"] == rounds, step
-    # one peek a round, and the closing one that finds no query active
-    assert names["dispatch/verify/peek"] == rounds + 1
-    assert set(names) == {"dispatch", "dispatch/order", "dispatch/verify"} \
-        | {f"dispatch/verify/{s}" for s in ROUND_STEPS}
+    if verify == "host":
+        for step in ("take", "dist", "merge"):
+            assert names[f"dispatch/verify/{step}"] == rounds, step
+        # one peek a round, and the closing one that finds none active
+        assert names["dispatch/verify/peek"] == rounds + 1
+        steps = {f"dispatch/verify/{s}" for s in ROUND_STEPS}
+    else:
+        # every round in one device program: one span, fenced on the
+        # fetched result, and no step spans
+        assert names["dispatch/verify/loop"] == 1
+        assert tr.get("device_loop") == 1
+        steps = {"dispatch/verify/loop"}
+    assert set(names) == {"dispatch", "dispatch/order",
+                          "dispatch/verify"} | steps
     root = tr.spans[0]
     assert root.name == "dispatch"
     assert root.meta["rids"] == [r.rid for r in reqs]
@@ -94,8 +103,9 @@ def test_dispatch_span_tree(verify):
     # the existing readers' suffix matches see the nested spans
     assert tr.has_span("order") and tr.has_span("verify")
     assert tr.span_seconds("verify") == by["dispatch/verify"].seconds
-    assert tr.span_seconds("take") == pytest.approx(
-        sum(s.seconds for s in tr.spans if s.name.endswith("/take")))
+    step = "take" if verify == "host" else "loop"
+    assert tr.span_seconds(step) == pytest.approx(
+        sum(s.seconds for s in tr.spans if s.name.endswith("/" + step)))
 
 
 def test_every_request_carries_its_dispatch_time():
@@ -115,23 +125,31 @@ def test_every_request_carries_its_dispatch_time():
             r.t_submit, r.t_dispatch, r.t_done)
 
 
-def test_explain_renders_rounds_summed_by_path():
+@pytest.mark.parametrize("verify", ["host", "device"])
+def test_explain_renders_rounds_summed_by_path(verify):
     """EXPLAIN prints each nested path once, with its count and total,
-    and takes its phases from the children of the dispatch root."""
+    takes its phases from the children of the dispatch root, and prints
+    the round table whichever loop ran the rounds."""
     from repro.obs import check_trace, render_trace
     Q, D = _data()
-    tr = _serve_one_batch(_engine("device", D), Q, k=8)[0].trace
+    tr = _serve_one_batch(_engine(verify, D), Q, k=8)[0].trace
     out = render_trace(tr)
     rounds = len(tr.rounds)
-    assert check_trace(tr, device=True) == []
+    assert check_trace(tr, device=verify == "device") == []
     phases = next(ln for ln in out.splitlines()
                   if ln.startswith("phases:"))
     assert phases.startswith("phases: order ")
     assert "| verify " in phases and "dispatch " in phases
     nested = [ln for ln in out.splitlines() if ln.startswith("  .. ")]
-    assert [ln.split()[1] for ln in nested] == [
-        f"verify/{s}" for s in ROUND_STEPS]
-    assert nested[0].endswith(f" x{rounds + 1}")
+    if verify == "host":
+        assert [ln.split()[1] for ln in nested] == [
+            f"verify/{s}" for s in ROUND_STEPS]
+        assert nested[0].endswith(f" x{rounds + 1}")
+    else:
+        assert [ln.split()[1] for ln in nested] == ["verify/loop"]
+        assert " x" not in nested[0]
+    table = [ln for ln in out.splitlines() if ln.split()[1:2] == ["scan"]]
+    assert len(table) == rounds
 
 
 def _subprocess(code: str, devices: int = 1) -> str:
@@ -146,9 +164,12 @@ def _subprocess(code: str, devices: int = 1) -> str:
     return r.stdout.strip().splitlines()[-1]
 
 
-#: role -> the module name its program lowers to on four devices
-ROLES = ("encode_rows", "repr_dists", "repr_topk", "rr_place", "rr_grow",
-         "rr_bounds", "rr_topk", "rr_rows_verify", "rr_windows_gather")
+#: program -> the module name it lowers to on four devices; the device
+#: round loop is the row-verify program, named as the one-round one
+ROLES = {r: r for r in ("encode_rows", "repr_dists", "repr_topk",
+                        "rr_place", "rr_grow", "rr_bounds", "rr_topk",
+                        "rr_rows_verify", "rr_windows_gather")}
+ROLES["rr_verify_loop"] = "rr_rows_verify"
 
 
 @pytest.fixture(scope="module")
@@ -187,6 +208,11 @@ def module_names():
                 buf, x[:2], cand, per),
             "rr_windows_gather": D._rr_windows_gather_fn(
                 mesh, 4, 3, 60, 120).lower(buf, cand, per),
+            "rr_verify_loop": D._rr_verify_loop_fn(mesh, 4, 4, 8, 5).lower(
+                buf, x[:2], jnp.zeros((2, 32), jnp.float32),
+                jnp.zeros((2, 32), jnp.int32), jnp.zeros(2, jnp.int32),
+                jnp.zeros(2, jnp.int32), jnp.zeros((2, 4), jnp.float32),
+                jnp.zeros((2, 4), jnp.int32), per),
         }
         print(json.dumps({r: l.as_text().split(None, 2)[1]
                           for r, l in low.items()}))
@@ -194,9 +220,9 @@ def module_names():
     return json.loads(out)
 
 
-@pytest.mark.parametrize("role", ROLES)
+@pytest.mark.parametrize("role", sorted(ROLES))
 def test_sharded_program_is_named_for_its_role(module_names, role):
-    assert module_names[role] == f"@jit_{role}"
+    assert module_names[role] == f"@jit_{ROLES[role]}"
 
 
 def test_profiler_annotations_reach_the_device_trace_reader():
@@ -241,4 +267,4 @@ def test_profiler_annotations_reach_the_device_trace_reader():
     marked = {line: c for line, c in got["lines"].items() if c}
     assert len(marked) == 1, marked
     assert next(iter(marked.values())) == got["spans"]
-    assert got["spans"]["repro/dispatch/verify/dist"] >= 1
+    assert got["spans"]["repro/dispatch/verify/loop"] == 1

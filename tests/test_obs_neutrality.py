@@ -96,6 +96,11 @@ def test_match_engine_neutral_all_paths(tech):
             res = engine.topk(Q, k=k, source=source, explain=True)
             _assert_identical(base, _fingerprint(res, engine.store),
                               label)
+            # the device engine's linear sweep runs its rounds as one
+            # device program: neutrality holds on that path too
+            if source is None:
+                assert res.device_loop == (verify == "device"), label
+                assert res.trace.get("device_loop") == int(res.device_loop)
             _check(res.trace, device=(verify == "device"))
             # replaying untraced after the traced run is unchanged too
             engine.store.reset()
@@ -205,3 +210,35 @@ def test_session_answers_neutral_to_explain(verify):
             assert np.array_equal(a.distances, b.distances), tier
             assert a.tier_served == b.tier_served == tier
         _check(traced[0].trace, device=(verify == "device"))
+
+
+@pytest.mark.parametrize("tech", TECHS)
+def test_device_loop_makes_no_round_trips(tech, monkeypatch):
+    """On the device loop, tracing on or off, no round calls the
+    stream's ``peek``/``take`` or the per-round ``dist_fn``: the host
+    launches one program and fetches once, and the answers are those of
+    the host round loop over the same stream."""
+    import jax.numpy as jnp
+    from repro.core import distributed as dist
+    T, n, n_q, k = 240, 64, 3, 4
+    X = season_dataset(n + n_q, T, L, 0.7, per_series_strength=True,
+                       seed=17)
+    Q, D = X[:n_q], X[n_q:]
+    dev = dist.make_engine_service(_enc(tech, T), jnp.asarray(D), _mesh1(),
+                                   batch_size=16, verify="device")
+    host = dist.make_engine_service(_enc(tech, T), jnp.asarray(D), _mesh1(),
+                                    batch_size=16, verify="host")
+    want = host.topk(Q, k=k)
+
+    def refuse(*a, **kw):
+        raise AssertionError("a per-round host call on the device loop")
+
+    monkeypatch.setattr(dist.DeviceOrderedStream, "peek", refuse)
+    monkeypatch.setattr(dist.DeviceOrderedStream, "take", refuse)
+    monkeypatch.setattr(dist, "cand_dists_rows_rr", refuse)
+    for explain in (False, True):
+        res = dev.topk(Q, k=k, explain=explain)
+        assert res.device_loop
+        assert np.array_equal(res.indices, want.indices)
+        assert np.array_equal(res.distances, want.distances)
+        assert np.array_equal(res.raw_accesses, want.raw_accesses)

@@ -115,3 +115,36 @@ def test_rows_verify_program_fits(topo, monkeypatch, chips):
         jax.clear_caches()
     assert "tpu_custom_call" in c.as_text()
     assert c.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_rows_verify_loop_fits(topo, monkeypatch, chips):
+    """Every verification round of a dispatch in one program, over a
+    stream as wide as the mirror: the kernel is in the program, and its
+    temp is still about one gathered candidate batch (the mirror is read
+    in place, not relaid or copied into the loop)."""
+    from repro.core.distributed import _LANES, _rr_verify_loop_fn
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    jax.clear_caches()
+    try:
+        mesh = Mesh(np.asarray(topo.devices[:chips]), ("data",))
+        width = T + (-T) % _LANES
+        rows = NamedSharding(mesh, P("data", None))
+        rep = NamedSharding(mesh, P())
+        q_n, batch, k = 8, 256, 32
+        c_w = chips * MIRROR_ROWS
+
+        def arg(shape, dtype, sharding=rep):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        c = _rr_verify_loop_fn(mesh, chips, k, batch,
+                               -(-c_w // batch) + 1).lower(
+            arg((c_w, width), F32, rows), arg((q_n, T), F32),
+            arg((q_n, c_w), F32), arg((q_n, c_w), I32), arg((q_n,), I32),
+            arg((q_n,), I32), arg((q_n, k), F32), arg((q_n, k), I32),
+            arg((), I32)).compile()
+    finally:
+        jax.clear_caches()
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
