@@ -27,16 +27,16 @@ row axis is sharded over the data axes — shard ``s`` holds the
 contiguous block ``[s * capacity, (s + 1) * capacity)``, so each
 shard's local block is ``(capacity, *rest)`` in its natural layout
 (a size-1 leading shard axis made the TPU compiler relayout the whole
-local block on every call).  A head-aligned append of
-``d * n_shards`` rows therefore lands in slots
-``[per_live, per_live + d)`` of EVERY shard — host->device traffic is
+local block on every call).  Every row of the corpus is on the device,
+whatever the row count: an append lands in the slots from the first
+one not yet full on EVERY shard (re-uploading the < n_shards rows that
+slot already held, with the same values) — host->device traffic is
 O(chunk) and the resident corpus is never re-laid-out, unlike a
 contiguous-range layout where each append shifts every shard boundary
 (O(corpus) collective re-layout).  Capacity doubles device-side
 (``jnp.pad``, no host traffic), so amortized append cost stays O(chunk).
-The largest shard-divisible prefix (the "head", always a multiple of
-``n_shards``) lives in the mirrors; the < n_shards remainder (the
-"tail") is swept host-side through the same kernel math and min-merged.
+Every program masks a slot as dead by its global id
+(``slot * n_shards + shard >= live``).
 
 The ON-DISK layout is deliberately NOT the mirror layout: snapshots
 (``store.snapshot``) keep contiguous per-host row ranges
@@ -48,7 +48,7 @@ because every per-(query, row) quantity is computed element-wise.
 
 Device-resident candidate ORDER: the bound matrix never materializes on
 the host for the exact path.  ``candidate_stream`` sorts the blocked
-round-robin bound matrix (plus the tail) by ``(bound, id)`` once, on
+round-robin bound matrix by ``(bound, id)`` once, on
 device, and hands ``core.engine.topk_verify`` a
 :class:`DeviceOrderedStream` — ``peek``/``take`` move only O(Q) /
 O(Q·batch) scalars and ids per round, never the (Q, N) matrix
@@ -63,12 +63,10 @@ min-merge combines shards.  The distance definition is the kernel's f32
 reduction — identical math to the host ``verify="host"`` fallback
 (store fetch + the same kernel), so the two paths are bit-identical;
 the host ``verify="numpy"`` path stays the brute-force oracle with
-modeled I/O.  Tail rows are distanced host-side through the same
-kernel — they are already host-resident, so the device path still
-moves zero raw rows device->host.  With no tail, a whole stream's
-rounds run as one device program (:func:`verify_stream_rr`): peek,
-take, distances, square root and merge in a ``lax.while_loop``, one
-launch and one fetch per call.
+modeled I/O.  The device path moves zero raw rows device->host, and a
+whole stream's rounds run as one device program
+(:func:`verify_stream_rr`): peek, take, distances, square root and
+merge in a ``lax.while_loop``, one launch and one fetch per call.
 
 The helpers take any encoder with ``encode`` + ``pairwise_distance`` —
 SAX, sSAX, tSAX and 1d-SAX all plug in.
@@ -76,8 +74,9 @@ SAX, sSAX, tSAX and 1d-SAX all plug in.
 
 from __future__ import annotations
 
+import math
 import threading
-from functools import lru_cache, partial
+from functools import lru_cache
 from typing import Callable
 
 import jax
@@ -88,6 +87,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 def _data_axes(mesh: Mesh):
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+
+
+def _n_shards(mesh: Mesh) -> int:
+    """Number of shards over the data axes."""
+    return math.prod(mesh.shape[a] for a in _data_axes(mesh))
 
 
 # The shard_map'd sweep callables are built once per (mesh, encoder /
@@ -146,14 +150,11 @@ def rowwise_sharded(obj, method: str, rows, mesh: Mesh):
     fn = getattr(obj, method)
     if m == 0:
         return jax.tree.map(np.asarray, fn(jnp.asarray(rows)))
-    axes = _data_axes(mesh)
-    n_shards = 1
-    for a in axes:
-        n_shards *= mesh.shape[a]
-    pad = (-m) % n_shards
+    pad = (-m) % _n_shards(mesh)
     if pad:
         rows = np.concatenate([rows, rows[-1:].repeat(pad, axis=0)])
-    sharded = jax.device_put(rows, NamedSharding(mesh, P(axes, None)))
+    sharded = jax.device_put(rows,
+                             NamedSharding(mesh, P(_data_axes(mesh), None)))
     return jax.tree.map(lambda l: np.asarray(l)[:m], fn(sharded))
 
 
@@ -241,6 +242,11 @@ def _shard_index(axes):
     return sid
 
 
+def _slot_ids(cap: int, n_shards: int, axes):
+    """(cap,) global row ids of the executing shard's local slots."""
+    return jnp.arange(cap) * n_shards + _shard_index(axes)
+
+
 #: Rows per sharded encode call during ingest (256 MB of f32 at T=960)
 _ENCODE_ROWS = 1 << 16
 
@@ -292,17 +298,18 @@ class RoundRobinMirror:
     Global row ``i`` lives on shard ``i % n_shards`` at local slot
     ``i // n_shards``; the device buffer is ``(n_shards * capacity,
     *rest)`` with its row axis sharded over the mesh data axes, so shard
-    ``s`` holds rows ``[s * capacity, (s + 1) * capacity)`` of it.  An
-    append of ``d * n_shards`` rows uploads exactly those rows
-    (O(chunk) host->device, counted in ``h2d_bytes``), each shard's
-    share straight to its own device, into slots
-    ``[per_live, per_live + d)`` of every shard — the resident corpus
+    ``s`` holds rows ``[s * capacity, (s + 1) * capacity)`` of it.  The
+    mirror holds every one of the ``live`` rows, whatever their count.
+    ``sync`` uploads only the rows from the first slot not yet full —
+    the new rows plus the < n_shards already in that slot, with the same
+    values (O(chunk) host->device, counted in ``h2d_bytes``), each
+    shard's share straight to its own device — so the resident corpus
     is never re-uploaded or re-laid-out, unlike a contiguous-range
     layout where every append shifts every shard boundary.  Capacity
     doubles device-side when exhausted (``jnp.pad``, no host traffic),
-    so amortized append cost stays O(chunk).  Slots ``>= per_live`` are
-    dead padding; every consumer masks them via the ``per_live``
-    scalar.
+    so amortized append cost stays O(chunk).  A slot whose global id
+    ``slot * n_shards + shard`` is ``>= live`` is dead (zeros); every
+    consumer masks it via the ``live`` scalar.
 
     ``lanes``: when set, 2-D rows are zero-padded on the trailing axis
     to a multiple of ``lanes`` at upload.  The raw-row mirrors use the
@@ -317,54 +324,52 @@ class RoundRobinMirror:
         self.n_shards = int(n_shards)
         self.lanes = int(lanes)
         self.buf = None                  # (S * cap, *rest) device array
-        self.per_live = 0                # live slots per shard
+        self.live = 0                    # rows held (global count)
         self.h2d_bytes = 0               # host->device upload accounting
 
     @property
     def cap(self) -> int:
         return 0 if self.buf is None else self.buf.shape[0] // self.n_shards
 
-    @property
-    def live(self) -> int:
-        return self.per_live * self.n_shards
-
-    def append(self, rows) -> None:
-        """Upload ``rows`` (a head-aligned multiple of n_shards, in
-        global row order) into the next free slot of every shard."""
-        rows = np.asarray(rows)
+    def sync(self, rows, dtype=None) -> None:
+        """Mirror ``rows``: the whole corpus so far, in global row order,
+        whose first ``live`` rows are those already mirrored (a
+        prefix-stable host array; only its unmirrored slots are read,
+        and cast to ``dtype`` if given)."""
         S = self.n_shards
-        if rows.shape[0] % S:
-            raise ValueError(f"append of {rows.shape[0]} rows is not a "
-                             f"multiple of n_shards={S}")
-        d = rows.shape[0] // S
-        if d == 0:
+        n = rows.shape[0]
+        if n <= self.live:
             return
-        rest = rows.shape[1:]
-        pad = (-rest[-1]) % self.lanes if self.lanes and rows.ndim == 2 \
-            else 0
-        if pad:
-            rest = (rest[0] + pad,)
+        j0 = self.live // S              # first slot not yet full
+        d = -(-n // S) - j0              # slots rewritten on every shard
+        src = np.asarray(rows[j0 * S:n], dtype)
+        lane = (-src.shape[-1]) % self.lanes if self.lanes and \
+            src.ndim == 2 else 0
+        rest = (src.shape[1] + lane,) if lane else src.shape[1:]
 
-        # appended row j*S + s -> shard s, slot per_live + j: shard s's
-        # delta block is the strided slice rows[s::S], staged per device
+        # row j0*S + j*S + s -> shard s, slot j0 + j: shard s's block is
+        # the strided slice src[s::S], zero-filled past the last row
         def block(idx):
-            blk = rows[(idx[0].start or 0) // d::S]
-            return (np.pad(blk, ((0, 0), (0, pad))) if pad
-                    else np.ascontiguousarray(blk))
+            blk = src[(idx[0].start or 0) // d::S]
+            if blk.shape[0] == d and not lane:
+                return np.ascontiguousarray(blk)
+            pad = [(0, d - blk.shape[0])] + [(0, 0)] * (src.ndim - 1)
+            if lane:
+                pad[-1] = (0, lane)
+            return np.pad(blk, pad)
 
-        sh = NamedSharding(self.mesh, _row_spec(self.mesh, rows.ndim))
+        sh = NamedSharding(self.mesh, _row_spec(self.mesh, src.ndim))
         dev = jax.make_array_from_callback((S * d,) + rest, sh, block)
-        self.h2d_bytes += rows.nbytes
+        self.h2d_bytes += src.nbytes
         if self.buf is None:
             self.buf = dev
         else:
-            if self.per_live + d > self.cap:
-                new_cap = max(2 * self.cap, self.per_live + d)
-                self.buf = _rr_grow_fn(self.mesh, self.buf.ndim, new_cap)(
-                    self.buf)
+            if j0 + d > self.cap:
+                self.buf = _rr_grow_fn(self.mesh, self.buf.ndim,
+                                       max(2 * self.cap, j0 + d))(self.buf)
             self.buf = _rr_place_fn(self.mesh, self.buf.ndim)(
-                self.buf, dev, jnp.int32(self.per_live))
-        self.per_live += d
+                self.buf, dev, jnp.int32(j0))
+        self.live = n
 
 
 # ---------------------------------------------------------------------------
@@ -375,17 +380,18 @@ class RoundRobinMirror:
 def _rr_bounds_fn(mesh: Mesh, pw, q_def, x_def, q_ndims, x_ndims):
     """(Q, S*cap) blocked bound matrix over round-robin mirrors: the
     block column ``s*cap + j`` holds global row ``j*S + s``; dead slots
-    are +inf.  Output stays column-sharded on device — the host
-    unpermute (``ShardedRepSweep.repr_distances``) is the legacy matrix
-    path only."""
+    (global id ``>= live``) are +inf.  Output stays column-sharded on
+    device — the host unpermute (``ShardedRepSweep.repr_distances``) is
+    the legacy matrix path only."""
     axes = _data_axes(mesh)
+    n_shards = _n_shards(mesh)
     in_q = jax.tree.unflatten(q_def, [P(*([None] * nd)) for nd in q_ndims])
     in_x = jax.tree.unflatten(
         x_def, [P(axes, *([None] * (nd - 1))) for nd in x_ndims])
 
-    def rr_bounds(rq, rx, per):
+    def rr_bounds(rq, rx, live):
         d = pw(rq, rx)                                 # (Q, cap)
-        dead = jnp.arange(d.shape[1])[None, :] >= per
+        dead = _slot_ids(d.shape[1], n_shards, axes)[None, :] >= live
         return jnp.where(dead, jnp.inf, d)
 
     return jax.jit(jax.shard_map(
@@ -407,10 +413,11 @@ def _rr_topk_fn(mesh: Mesh, pw, k: int, n_shards: int,
     in_x = jax.tree.unflatten(
         x_def, [P(axes, *([None] * (nd - 1))) for nd in x_ndims])
 
-    def rr_topk(rq, rx, per):
+    def rr_topk(rq, rx, live):
         d = pw(rq, rx)                                 # (Q, cap)
         cap = d.shape[1]
-        d = jnp.where(jnp.arange(cap)[None, :] >= per, jnp.inf, d)
+        d = jnp.where(_slot_ids(cap, n_shards, axes)[None, :] >= live,
+                      jnp.inf, d)
         kk = min(k, cap)
         neg, idx = jax.lax.top_k(-d, kk)
         cd = -neg
@@ -448,12 +455,11 @@ def _rr_rows_verify_fn(mesh: Mesh, n_shards: int):
     folds repeated (Qa, B, T) round shapes)."""
     axes = _data_axes(mesh)
 
-    def rr_rows_verify(x, q, c, per):
+    def rr_rows_verify(x, q, c, live):
         cap = x.shape[0]                              # x: (cap, T_pad) local
-        slot = c // n_shards
         valid = ((c >= 0) & (c % n_shards == _shard_index(axes))
-                 & (slot < per))
-        rows = x[jnp.clip(slot, 0, cap - 1), :q.shape[-1]]   # (Qa, B, T)
+                 & (c < live))
+        rows = x[jnp.clip(c // n_shards, 0, cap - 1), :q.shape[-1]]
         d2 = _kernel_cand_d2(rows, q)
         # each candidate is owned by exactly one shard: min-merge
         return jax.lax.pmin(jnp.where(valid, d2, jnp.inf), axes)
@@ -514,18 +520,18 @@ _ULPS = 8
 
 
 def cand_dists_rows_rr(raw_buf, q_dev, cand, mesh: Mesh, n_shards: int,
-                       per_live: int) -> np.ndarray:
+                       live: int) -> np.ndarray:
     """True d_ED of candidate ROW ids against a round-robin raw mirror.
 
-    raw_buf: the mirror's (S * cap, T) device buffer.  q_dev: (Qa, T)
-    replicated queries.  cand: (Qa, B) int ids, -1 padding.  Ids outside
-    the mirrored head return +inf (the caller min-merges the host-side
-    tail).  Raw rows never leave the devices; only the (Qa, B) squared
-    distances do, and the square root is taken on the host exactly as
-    ``core.engine.kernel_verifier`` takes it, so the device and host
-    paths cannot differ by a backend's sqrt rounding."""
+    raw_buf: the mirror's (S * cap, T) device buffer, holding rows
+    ``[0, live)``.  q_dev: (Qa, T) replicated queries.  cand: (Qa, B)
+    int ids, -1 padding, which return +inf.  Raw rows never leave the
+    devices; only the (Qa, B) squared distances do, and the square root
+    is taken on the host exactly as ``core.engine.kernel_verifier``
+    takes it, so the device and host paths cannot differ by a backend's
+    sqrt rounding."""
     d2 = np.asarray(_rr_rows_verify_fn(mesh, int(n_shards))(
-        raw_buf, q_dev, jnp.asarray(cand), jnp.int32(per_live)))
+        raw_buf, q_dev, jnp.asarray(cand), jnp.int32(live)))
     return np.sqrt(np.maximum(d2, 0.0))
 
 
@@ -547,7 +553,7 @@ def _rr_verify_loop_fn(mesh: Mesh, n_shards: int, k: int, batch: int,
     axes = _data_axes(mesh)
     big = jnp.iinfo(jnp.int32).max
 
-    def rr_rows_verify(x, q, sb, si, n_fin, pos, front_d, front_i, per):
+    def rr_rows_verify(x, q, sb, si, n_fin, pos, front_d, front_i, live):
         cap = x.shape[0]                              # x: (cap, T_pad) local
         last = sb.shape[1] - 1
         sid = _shard_index(axes)
@@ -566,9 +572,8 @@ def _rr_verify_loop_fn(mesh: Mesh, n_shards: int, k: int, batch: int,
             real = active[:, None] & (at < n_fin[:, None])
             cand = jnp.where(real, jnp.take_along_axis(
                 si, jnp.minimum(at, last), axis=1), -1)
-            slot = cand // n_shards
-            valid = real & (cand % n_shards == sid) & (slot < per)
-            rows = x[jnp.clip(slot, 0, cap - 1), :q.shape[-1]]
+            valid = real & (cand % n_shards == sid) & (cand < live)
+            rows = x[jnp.clip(cand // n_shards, 0, cap - 1), :q.shape[-1]]
             d2 = jax.lax.pmin(jnp.where(valid, _kernel_cand_d2(rows, q),
                                         jnp.inf), axes)
             d = jnp.where(real, _sqrt_rn(jnp.maximum(d2, 0.0)), jnp.inf)
@@ -602,10 +607,10 @@ def _rr_verify_loop_fn(mesh: Mesh, n_shards: int, k: int, batch: int,
 
 
 def verify_stream_rr(raw_buf, q_dev, stream, front_d, front_i, mesh: Mesh,
-                     n_shards: int, per_live: int, batch: int, *,
+                     n_shards: int, live: int, batch: int, *,
                      rounds: bool = False):
     """Every verification round of ``stream`` (a non-empty
-    :class:`DeviceOrderedStream` of ids in the mirrored head) in one
+    :class:`DeviceOrderedStream` of ids the mirror holds) in one
     device program (:func:`_rr_verify_loop_fn`): one launch, one fetch.
     Returns ``(front_d, front_i, taken, n_rounds, per_round)``: the final
     (Q, k) frontier (float64 / int64 on the host, as the host loop keeps
@@ -622,7 +627,7 @@ def verify_stream_rr(raw_buf, q_dev, stream, front_d, front_i, mesh: Mesh,
     out = fn(raw_buf, q_dev, stream._b, stream._i,
              stream._n_fin.astype(np.int32), pos0.astype(np.int32),
              np.asarray(front_d, np.float32), np.asarray(front_i, np.int32),
-             np.int32(per_live))
+             np.int32(live))
     r, pos, fd, fi = jax.device_get(out[:4])
     r = int(r)
     stream._pos = pos.astype(np.int64)
@@ -644,14 +649,13 @@ def _rr_windows_gather_fn(mesh: Mesh, n_shards: int, nw: int, stride: int,
     re-assembles the full batch (x + 0 is exact in f32)."""
     axes = _data_axes(mesh)
 
-    def rr_windows_gather(x, c, per):
+    def rr_windows_gather(x, c, live):
         cap = x.shape[0]                   # x: (cap, T_src [+ lane pad])
         row = jnp.where(c >= 0, c // nw, -1)
         start = (c % nw) * stride          # in-bounds even for c == -1
-        slot = row // n_shards
         valid = ((c >= 0) & (row % n_shards == _shard_index(axes))
-                 & (slot < per))
-        slab = x[jnp.clip(slot, 0, cap - 1)]          # (Qa, B, T_pad)
+                 & (row < live))
+        slab = x[jnp.clip(row // n_shards, 0, cap - 1)]   # (Qa, B, T_pad)
         gat = start[..., None] + jnp.arange(m)[None, None, :]
         w = jnp.take_along_axis(slab, gat, axis=2)    # (Qa, B, m)
         return jax.lax.psum(jnp.where(valid[..., None], w, 0.0), axes)
@@ -663,13 +667,13 @@ def _rr_windows_gather_fn(mesh: Mesh, n_shards: int, nw: int, stride: int,
 
 
 def cand_dists_windows_rr(raw_buf, q_dev, cand, mesh: Mesh, *,
-                          n_shards: int, per_live: int, nw: int,
-                          stride: int, m: int,
-                          head_rows: int) -> np.ndarray:
+                          n_shards: int, n_rows: int, nw: int,
+                          stride: int, m: int) -> np.ndarray:
     """True z-normalized d_ED of candidate WINDOW ids against windows of
     round-robin-mirrored SOURCE rows (``repro.subseq.WindowView``
     geometry: ``wid = row * nw + j`` covers
-    ``source[row, j*stride : j*stride+m]``).
+    ``source[row, j*stride : j*stride+m]``); the mirror holds rows
+    ``[0, n_rows)``.
 
     Each shard extracts its own rows' windows on device (sharded
     gather); the assembled device batch is then z-normalized and
@@ -677,51 +681,17 @@ def cand_dists_windows_rr(raw_buf, q_dev, cand, mesh: Mesh, *,
     jitted euclid-kernel pipeline the host ``WindowView.fetch`` +
     kernel-verifier path runs — z-normalization must not be fused into
     a larger jit graph, or XLA re-associates its reductions and the
-    device path drifts from the host path by an ulp.  Window ids whose
-    source row falls outside the mirrored head return +inf (the caller
-    min-merges the host-side tail); window values never reach the
-    host."""
+    device path drifts from the host path by an ulp.  Padding ids (-1)
+    return +inf; window values never reach the host."""
     from repro.core.normalize import znormalize
     fn = _rr_windows_gather_fn(mesh, int(n_shards), int(nw), int(stride),
                                int(m))
-    w = fn(raw_buf, jnp.asarray(cand), jnp.int32(per_live))
+    w = fn(raw_buf, jnp.asarray(cand), jnp.int32(n_rows))
     wz = znormalize(w)                   # eager: host-identical dispatch
     d2 = np.asarray(_kernel_cand_d2(wz, q_dev))  # one host transfer
     out = np.sqrt(np.maximum(d2, 0.0))
-    row = np.where(cand >= 0, cand // nw, -1)
-    valid = (cand >= 0) & (row < head_rows)
+    valid = (cand >= 0) & (cand // nw < n_rows)
     return np.where(valid, out, np.float32(np.inf)).astype(np.float32)
-
-
-def _host_cand_dists_rows(tail_rows, lo, qs, cand) -> np.ndarray:
-    """Host twin of :func:`cand_dists_rows_rr` for the
-    non-shard-divisible tail remainder — same kernel distance math; the
-    tail rows are already host-resident, so nothing moves off device."""
-    loc = cand - lo
-    valid = (cand >= 0) & (loc >= 0) & (loc < tail_rows.shape[0])
-    rows = tail_rows[np.clip(loc, 0, tail_rows.shape[0] - 1)]
-    d2 = np.asarray(_kernel_cand_d2(jnp.asarray(rows, jnp.float32),
-                                    jnp.asarray(qs, jnp.float32)))
-    return np.where(valid, np.sqrt(np.maximum(d2, 0.0)),
-                    np.float32(np.inf)).astype(np.float32)
-
-
-def _host_cand_dists_windows(tail_rows, row_lo, qs, cand, *, nw: int,
-                             stride: int, m: int) -> np.ndarray:
-    """Host twin of :func:`cand_dists_windows_rr` for windows whose
-    source row lives in the tail remainder."""
-    from repro.subseq.windows import znorm_windows
-    row = np.where(cand >= 0, cand // nw, -1)
-    start = (cand % nw) * stride
-    loc = row - row_lo
-    valid = (cand >= 0) & (loc >= 0) & (loc < tail_rows.shape[0])
-    slab = tail_rows[np.clip(loc, 0, tail_rows.shape[0] - 1)]
-    gat = start[..., None] + np.arange(m)[None, None, :]
-    wz = znorm_windows(np.take_along_axis(slab, gat, axis=2))
-    d2 = np.asarray(_kernel_cand_d2(jnp.asarray(wz),
-                                    jnp.asarray(qs, jnp.float32)))
-    return np.where(valid, np.sqrt(np.maximum(d2, 0.0)),
-                    np.float32(np.inf)).astype(np.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -845,15 +815,12 @@ class ShardedRepSweep:
       trimmed) — and appends rows + representation to the store.  Nothing
       already ingested is re-encoded, ever.
     * On the next query the mirrors are refreshed incrementally: only
-      the newly appended head-aligned rows are uploaded, landing in the
-      next free slot of every shard — host->device traffic AND device
-      work per ingest are O(chunk), not O(corpus) (the contiguous-range
-      layout this replaced re-laid-out the entire resident corpus on
-      every shard-boundary shift).  The largest shard-divisible prefix
-      lives in the mirrors; the small remainder (< n_shards rows) is
-      swept host-side (``_tail_bounds`` — one shared helper for the
-      matrix, frontier and stream sweeps) and merged, so any corpus
-      size serves exact answers between ingests.
+      the newly appended rows (and the < n_shards rows sharing their
+      first slot) are uploaded, landing in the next slots of every
+      shard — host->device traffic AND device work per ingest are
+      O(chunk), not O(corpus) (the contiguous-range layout this replaced
+      re-laid-out the entire resident corpus on every shard-boundary
+      shift).  Every row is on the device, at any row count.
     * ``candidate_stream`` orders the device-resident bounds by
       (bound, id) on device and hands ``topk_verify`` a
       :class:`DeviceOrderedStream` — the exact path never materializes
@@ -876,9 +843,7 @@ class ShardedRepSweep:
         self.store = store
         self._pw = pairwise or encoder.pairwise_distance
         self.axes = _data_axes(mesh)
-        self.n_shards = 1
-        for a in self.axes:
-            self.n_shards *= mesh.shape[a]
+        self.n_shards = _n_shards(mesh)
         self.mirror_raw = bool(mirror_raw)
         if self.mirror_raw and not getattr(store, "store_raw", True):
             raise ValueError("device-resident verification needs raw rows "
@@ -886,10 +851,10 @@ class ShardedRepSweep:
         self._synced_version = -1
         self._synced_n = 0               # row frontier the mirrors cover
         self._sync_lock = threading.Lock()
-        self._head = 0
         self._mirrors = None             # per-rep-leaf RoundRobinMirror
-        self._tail_rep = None            # host, < n_shards rows
-        self._raw_mirror = None          # RoundRobinMirror of raw rows
+        self._raw_mirror = (RoundRobinMirror(mesh, self.n_shards,
+                                             lanes=_LANES)
+                            if self.mirror_raw else None)
         self.host_order_bytes = 0        # bytes of host bound matrices
 
     # -- ingest -----------------------------------------------------------
@@ -924,10 +889,6 @@ class ShardedRepSweep:
         return self.store.append(rows, rep=self._encode_chunk(rows))
 
     # -- device mirror ----------------------------------------------------
-    def _restructure(self, leaves):
-        single = not isinstance(self.store.rep_view(), tuple)
-        return leaves[0] if single else tuple(leaves)
-
     def _sync(self):
         if self._synced_version == self.store.version:
             return
@@ -936,32 +897,22 @@ class ShardedRepSweep:
                 return
             from repro.store.symbolic import rep_leaves
             # Capture the frontier FIRST: a writer may append while we
-            # sync, so everything below (leaves, tail, version stamp)
-            # is sliced to this (version, n) pair — never the live
+            # sync, so everything below (leaves, version stamp) is
+            # sliced to this (version, n) pair — never the live
             # attributes, which could already be past it.
             version = self.store.version
             n = self.store.n
-            head = (n // self.n_shards) * self.n_shards
-            leaves = tuple(l[:n]
-                           for l in rep_leaves(self.store.rep_view()))
-            if head != self._head:
+            if n > self._synced_n:
+                leaves = rep_leaves(self.store.rep_view())
                 if self._mirrors is None:
                     self._mirrors = tuple(
                         RoundRobinMirror(self.mesh, self.n_shards)
                         for _ in leaves)
-                # O(chunk): only head-aligned delta rows are uploaded
+                # O(chunk): only the unmirrored slots are uploaded
                 for mir, l in zip(self._mirrors, leaves):
-                    mir.append(l[self._head:head])
+                    mir.sync(l[:n])
                 if self.mirror_raw:
-                    if self._raw_mirror is None:
-                        self._raw_mirror = RoundRobinMirror(
-                            self.mesh, self.n_shards, lanes=_LANES)
-                    self._raw_mirror.append(
-                        self.store.data[self._head:head])
-            self._tail_rep = (self._restructure(
-                tuple(jnp.asarray(l[head:]) for l in leaves))
-                if head < n else None)
-            self._head = head
+                    self._raw_mirror.sync(self.store.data[:n])
             self._synced_n = n
             self._synced_version = version
 
@@ -991,27 +942,15 @@ class ShardedRepSweep:
                 "h2d_bytes": int(self.h2d_bytes)}
 
     def _mirror_tree(self):
-        return self._restructure(tuple(m.buf for m in self._mirrors))
+        bufs = tuple(m.buf for m in self._mirrors)
+        single = not isinstance(self.store.rep_view(), tuple)
+        return bufs[0] if single else bufs
 
     def _rr_bounds(self, rep_q):
         """(Q, S*cap) blocked device bound matrix over the mirrors."""
         mt = self._mirror_tree()
         fn = _rr_bounds_fn(self.mesh, self._pw, *_rep_specs(rep_q, mt))
-        return fn(rep_q, mt, jnp.int32(self._mirrors[0].per_live))
-
-    def _tail_bounds(self, rep_q):
-        """Shared tail-remainder sweep: (device (Q, tn) bounds, int64
-        global ids) of the < n_shards host-resident rows, or (None,
-        None).  The one helper behind the matrix (``repr_distances``),
-        frontier (``candidates``) and stream (``candidate_stream``)
-        paths — previously duplicated near-identically per caller."""
-        if self._tail_rep is None:
-            return None, None
-        d = self._pw(rep_q, self._tail_rep)
-        # _synced_n, not the live store.n: a concurrent append may have
-        # grown the store past the frontier this tail was sliced at
-        ids = np.arange(self._head, self._synced_n, dtype=np.int64)
-        return d, ids
+        return fn(rep_q, mt, jnp.int32(self._mirrors[0].live))
 
     # -- sweeps -----------------------------------------------------------
     def repr_distances(self, queries_raw) -> np.ndarray:
@@ -1021,60 +960,40 @@ class ShardedRepSweep:
         ``host_order_bytes``).  The exact top-k path uses
         ``candidate_stream`` instead and never pays this."""
         self._sync()
-        rep_q = self.encoder.encode(jnp.asarray(queries_raw, jnp.float32))
-        parts = []
-        if self._mirrors is not None:
-            blk = np.asarray(self._rr_bounds(rep_q))   # (Q, S*cap)
-            S, cap = self.n_shards, self._mirrors[0].cap
-            # block column s*cap + j  ->  global row j*S + s; dead slots
-            # land at ids >= head and are trimmed
-            arr = np.ascontiguousarray(
-                blk.reshape(-1, S, cap).transpose(0, 2, 1)
-                .reshape(-1, S * cap)[:, :self._head])
-            self.host_order_bytes += arr.nbytes
-            parts.append(arr)
-        d_tail, _ = self._tail_bounds(rep_q)
-        if d_tail is not None:
-            parts.append(np.asarray(d_tail))
-        if not parts:
+        if self._mirrors is None:
             q_n = np.asarray(queries_raw).shape[0]
             return np.empty((q_n, 0), np.float32)
-        return parts[0] if len(parts) == 1 else np.concatenate(parts,
-                                                               axis=1)
+        rep_q = self.encoder.encode(jnp.asarray(queries_raw, jnp.float32))
+        blk = np.asarray(self._rr_bounds(rep_q))       # (Q, S*cap)
+        S, cap = self.n_shards, self._mirrors[0].cap
+        # block column s*cap + j  ->  global row j*S + s; dead slots land
+        # at ids >= n and are trimmed
+        arr = np.ascontiguousarray(
+            blk.reshape(-1, S, cap).transpose(0, 2, 1)
+            .reshape(-1, S * cap)[:, :self._synced_n])
+        self.host_order_bytes += arr.nbytes
+        return arr
 
     def candidates(self, queries_raw, k: int) -> np.ndarray:
-        """(Q, k) global candidate frontier: sharded local top-k + gather
-        over the mirrors, host top-k over the tail, host merge."""
-        from repro.core.engine import merge_topk_numpy
+        """(Q, k) global candidate frontier: sharded local top-k over the
+        mirrors, all-gathered and merged on device."""
         self._sync()
-        rep_q = self.encoder.encode(jnp.asarray(queries_raw, jnp.float32))
-        ds, idxs = [], []
-        if self._mirrors is not None:
-            mt = self._mirror_tree()
-            fn = _rr_topk_fn(self.mesh, self._pw, int(k), self.n_shards,
-                             *_rep_specs(rep_q, mt))
-            d, i = fn(rep_q, mt, jnp.int32(self._mirrors[0].per_live))
-            ds.append(np.asarray(d))
-            idxs.append(np.asarray(i, np.int64))
-        d_tail, tail_ids = self._tail_bounds(rep_q)
-        if d_tail is not None:
-            d_tail = np.asarray(d_tail)
-            ds.append(d_tail)
-            idxs.append(np.broadcast_to(tail_ids, d_tail.shape).copy())
-        if not ds:                       # empty corpus: no candidates yet
+        if self._mirrors is None:        # empty corpus: no candidates yet
             q_n = np.asarray(queries_raw).shape[0]
             return np.empty((q_n, 0), np.int64)
-        d_all = np.concatenate(ds, axis=1)
-        i_all = np.concatenate(idxs, axis=1)
-        _, out_i = merge_topk_numpy(d_all, i_all, min(k, d_all.shape[1]))
-        return out_i
+        rep_q = self.encoder.encode(jnp.asarray(queries_raw, jnp.float32))
+        mt = self._mirror_tree()
+        fn = _rr_topk_fn(self.mesh, self._pw, int(k), self.n_shards,
+                         *_rep_specs(rep_q, mt))
+        _, i = fn(rep_q, mt, jnp.int32(self._mirrors[0].live))
+        return np.asarray(i, np.int64)
 
     def candidate_stream(self, queries_raw,
                          mask_fn=None) -> DeviceOrderedStream:
         """Device-ordered exact candidate frontier: the blocked mirror
-        bounds and the tail bounds are concatenated and lexsorted by
-        (bound, global id) ON DEVICE — no (Q, N) host matrix, no host
-        argsort.  The stream yields global ids directly.
+        bounds are lexsorted by (bound, global id) ON DEVICE — no (Q, N)
+        host matrix, no host argsort.  The stream yields global ids
+        directly.
 
         ``mask_fn``, if given, maps the (C,) int64 global-id vector to
         a (Q, C) or (C,) boolean mask of candidates to SUPPRESS (their
@@ -1086,48 +1005,37 @@ class ShardedRepSweep:
         qs = np.asarray(queries_raw, np.float32)
         if qs.ndim == 1:
             qs = qs[None]
-        rep_q = self.encoder.encode(jnp.asarray(qs))
-        bparts, iparts = [], []
-        if self._mirrors is not None:
-            bparts.append(self._rr_bounds(rep_q))
-            cap = self._mirrors[0].cap
-            S = self.n_shards
-            # block column s*cap + j holds global row j*S + s (dead
-            # slots get ids >= head but their bounds are +inf, so the
-            # finite-frontier cursor never reaches them)
-            iparts.append((np.arange(cap, dtype=np.int64)[None, :] * S
-                           + np.arange(S, dtype=np.int64)[:, None])
-                          .reshape(-1))
-        d_tail, tail_ids = self._tail_bounds(rep_q)
-        if d_tail is not None:
-            bparts.append(d_tail)
-            iparts.append(tail_ids)
-        if not bparts:
+        if self._mirrors is None:
             return DeviceOrderedStream.empty(qs.shape[0])
-        b = (bparts[0] if len(bparts) == 1
-             else jnp.concatenate([jnp.asarray(p, jnp.float32)
-                                   for p in bparts], axis=1))
-        ids = np.concatenate(iparts)
+        b = self._rr_bounds(self.encoder.encode(jnp.asarray(qs)))
+        S, cap = self.n_shards, self._mirrors[0].cap
+        # block column s*cap + j holds global row j*S + s (dead slots get
+        # ids >= n but their bounds are +inf, so the finite-frontier
+        # cursor never reaches them)
+        ids = (np.arange(cap, dtype=np.int64)[None, :] * S
+               + np.arange(S, dtype=np.int64)[:, None]).reshape(-1)
         if mask_fn is not None:
             mask = jnp.asarray(mask_fn(jnp.asarray(ids)))
-            b = jnp.where(mask, jnp.float32(np.inf), jnp.asarray(b))
+            b = jnp.where(mask, jnp.float32(np.inf), b)
         return _order_stream(b, ids, width=self._synced_n)
 
     # -- device-resident verification -------------------------------------
     def shard_ranges(self):
-        """Contiguous row ranges of the device head — the SNAPSHOT raw
-        manifest's per-host unit (``store.snapshot._shard_ranges``).
-        This is deliberately NOT the device mirror layout (see
-        ``mirror_layout`` / ``owned_rows``): on-disk shards stay
-        contiguous, device placement is round-robin, and results are
-        identical either way."""
+        """Contiguous row ranges of the corpus over the shards — the
+        SNAPSHOT raw manifest's per-host unit
+        (``store.snapshot._shard_ranges``).  This is deliberately NOT
+        the device mirror layout (see ``mirror_layout`` /
+        ``owned_rows``): on-disk shards stay contiguous, device
+        placement is round-robin, and results are identical either
+        way."""
         from repro.store.snapshot import _shard_ranges
-        return _shard_ranges(self._head, self.n_shards)
+        return _shard_ranges(self._synced_n, self.n_shards)
 
     def owned_rows(self, shard: int) -> np.ndarray:
         """Global row ids resident on ``shard`` under the round-robin
         mirror layout (row ``i`` -> shard ``i % n_shards``)."""
-        return np.arange(shard, self._head, self.n_shards, dtype=np.int64)
+        return np.arange(shard, self._synced_n, self.n_shards,
+                         dtype=np.int64)
 
     def make_dist_fn(self, queries_raw):
         """Device-resident verification closure for one query batch:
@@ -1137,8 +1045,7 @@ class ShardedRepSweep:
         The contract matches ``core.engine.topk_verify``'s
         ``dist_fn``.
 
-        When every row is in the mirrored head (no host tail), the
-        closure also carries ``verify_loop(stream, front_d, front_i,
+        The closure also carries ``verify_loop(stream, front_d, front_i,
         batch, rounds=False)`` (:func:`verify_stream_rr`): the whole
         round loop over a device-ordered stream as one device program,
         which ``topk_verify`` runs instead of its host loop."""
@@ -1152,8 +1059,7 @@ class ShardedRepSweep:
             qs = qs[None]
         q_n = qs.shape[0]
         q_dev = jnp.asarray(qs)
-        head = self._head
-        n_syn = self._synced_n           # frontier at closure creation
+        mir = self._raw_mirror
 
         def dist(aq, cand):
             # pad the active-query batch back to the full query set so
@@ -1163,25 +1069,17 @@ class ShardedRepSweep:
             cand = np.asarray(cand, np.int64)
             full = np.full((q_n, cand.shape[1]), -1, np.int64)
             full[aq] = cand
-            out = np.full(full.shape, np.inf, np.float32)
-            if self._raw_mirror is not None and \
-                    ((full >= 0) & (full < head)).any():
-                out = np.minimum(out, cand_dists_rows_rr(
-                    self._raw_mirror.buf, q_dev, full, self.mesh,
-                    self.n_shards, self._raw_mirror.per_live))
-            if n_syn > head and (full >= head).any():
-                out = np.minimum(out, _host_cand_dists_rows(
-                    self.store.data[head:n_syn], head, qs, full))
-            return out[aq]
+            if not (full >= 0).any():
+                return np.full(cand.shape, np.inf, np.float32)
+            return cand_dists_rows_rr(mir.buf, q_dev, full, self.mesh,
+                                      self.n_shards, mir.live)[aq]
 
-        if self._raw_mirror is not None and n_syn == head:
-            def verify_loop(stream, front_d, front_i, batch, rounds=False):
-                return verify_stream_rr(
-                    self._raw_mirror.buf, q_dev, stream, front_d, front_i,
-                    self.mesh, self.n_shards, self._raw_mirror.per_live,
-                    batch, rounds=rounds)
+        def verify_loop(stream, front_d, front_i, batch, rounds=False):
+            return verify_stream_rr(
+                mir.buf, q_dev, stream, front_d, front_i, self.mesh,
+                self.n_shards, mir.live, batch, rounds=rounds)
 
-            dist.verify_loop = verify_loop
+        dist.verify_loop = verify_loop
         return dist
 
 
@@ -1263,9 +1161,9 @@ class ShardedWindowSweep:
       window representation exactly like whole-series matching — an
       inner :class:`ShardedRepSweep` over the view's representation
       store, so stride > 1 and ragged T (already folded into the window
-      geometry by ``WindowView``) and any non-shard-divisible window
-      count are handled by the same head/tail split, and window appends
-      refresh the round-robin mirrors in O(chunk).
+      geometry by ``WindowView``) and any window count are served from
+      the round-robin mirrors, which window appends refresh in
+      O(chunk).
     * ``candidate_stream`` is the inner sweep's device-ordered stream:
       window-representation rows ARE window ids, so the exact subsequence
       path feeds ``topk_verify`` without a host (Q, n_windows) matrix.
@@ -1274,9 +1172,8 @@ class ShardedWindowSweep:
       shard ``i % n_shards``); each shard slices and z-normalizes its
       own rows' windows (the same ``core.normalize.znormalize`` the host
       fetch path applies) and distances them through the multi-query
-      euclid kernel (:func:`cand_dists_windows_rr`).  Window values
-      never materialize on the host; rows of the tail remainder are
-      distanced host-side through the same kernel.
+      euclid kernel (:func:`cand_dists_windows_rr`).  Every source row
+      is on the device; window values never materialize on the host.
     """
 
     mirror_layout = "round_robin"
@@ -1288,9 +1185,9 @@ class ShardedWindowSweep:
         self.axes = self.rep_sweep.axes
         self.n_shards = self.rep_sweep.n_shards
         self.mirror_raw = bool(mirror_raw)
-        self._raw_mirror = None          # RoundRobinMirror of SOURCE rows
-        self._head_rows = 0
-        self._rows_synced = -1
+        self._raw_mirror = (RoundRobinMirror(mesh, self.n_shards,
+                                             lanes=_LANES)
+                            if self.mirror_raw else None)  # SOURCE rows
 
     def repr_distances(self, queries_z) -> np.ndarray:
         """(Q, n_windows) lower-bound matrix for already z-normalized
@@ -1325,21 +1222,9 @@ class ShardedWindowSweep:
 
     def _sync_raw(self):
         """Incremental round-robin mirror of the source rows
-        (append-only corpus: a row-count check is a complete freshness
-        test)."""
-        n_rows = self.view.n_rows
-        if n_rows == self._rows_synced:
-            return
-        head = (n_rows // self.n_shards) * self.n_shards
-        if head != self._head_rows:
-            if self._raw_mirror is None:
-                self._raw_mirror = RoundRobinMirror(
-                    self.mesh, self.n_shards, lanes=_LANES)
-            self._raw_mirror.append(
-                np.asarray(self.view.source.data[self._head_rows:head],
-                           np.float32))
-            self._head_rows = head
-        self._rows_synced = n_rows
+        (append-only corpus: only rows past the mirror's ``live`` are
+        read and uploaded)."""
+        self._raw_mirror.sync(self.view.source.data, np.float32)
 
     def make_dist_fn(self, queries_z):
         """Device-resident window verification closure (the
@@ -1355,9 +1240,7 @@ class ShardedWindowSweep:
         q_n = qs.shape[0]
         q_dev = jnp.asarray(qs)
         view = self.view
-        nw, stride, m = view.windows_per_row, view.stride, view.m
-        head_rows = self._head_rows
-        head_wid = head_rows * nw
+        mir = self._raw_mirror
 
         def dist(aq, cand):
             # full-Q padding: one (Q, B) shard_map shape per batch size
@@ -1365,18 +1248,11 @@ class ShardedWindowSweep:
             cand = np.asarray(cand, np.int64)
             full = np.full((q_n, cand.shape[1]), -1, np.int64)
             full[aq] = cand
-            out = np.full(full.shape, np.inf, np.float32)
-            if self._raw_mirror is not None and \
-                    ((full >= 0) & (full < head_wid)).any():
-                out = np.minimum(out, cand_dists_windows_rr(
-                    self._raw_mirror.buf, q_dev, full, self.mesh,
-                    n_shards=self.n_shards,
-                    per_live=self._raw_mirror.per_live,
-                    nw=nw, stride=stride, m=m, head_rows=head_rows))
-            if view.n_rows > head_rows and (full >= head_wid).any():
-                out = np.minimum(out, _host_cand_dists_windows(
-                    view.source.data[head_rows:], head_rows, qs, full,
-                    nw=nw, stride=stride, m=m))
-            return out[aq]
+            if not (full >= 0).any():
+                return np.full(cand.shape, np.inf, np.float32)
+            return cand_dists_windows_rr(
+                mir.buf, q_dev, full, self.mesh, n_shards=self.n_shards,
+                n_rows=mir.live, nw=view.windows_per_row,
+                stride=view.stride, m=view.m)[aq]
 
         return dist

@@ -63,9 +63,9 @@ Two loops run the rounds, with the same comparisons in the same order:
   batch shape.
 * The **device loop** (``core.distributed.verify_stream_rr``): when the
   candidates come from a device-ordered stream and are verified against
-  the raw device mirror with no host tail, every round — peek, take,
-  row distances, square root, merge — runs inside one device program
-  (a ``lax.while_loop``): one launch and one fetch per call instead of
+  the raw device mirror, every round — peek, take, row distances,
+  square root, merge — runs inside one device program (a
+  ``lax.while_loop``): one launch and one fetch per call instead of
   several host round trips per round.  It answers, and schedules its
   rounds, exactly as the host loop over the same stream does.
 """
@@ -239,9 +239,9 @@ def topk_verify(queries_raw, repr_dists, store: RawStore, *, k: int = 1,
 
     Which loop runs the rounds (module docstring): the device loop when
     a ``stream`` is given, ``dist_fn`` carries ``verify_loop`` (the
-    sharded sweep's, with every row in the mirrored head) and there is
-    no ``on_verified``; the host loop otherwise — matrix sources, host
-    verifiers, window verification, a host tail, exclusion widening.
+    sharded sweep's) and there is no ``on_verified``; the host loop
+    otherwise — matrix sources, host verifiers, window verification,
+    exclusion widening.
     Both give the same frontier, accesses and rounds (tested in
     tests/test_device_loop.py); ``TopKResult.device_loop`` says which
     ran.

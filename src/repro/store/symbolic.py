@@ -64,8 +64,8 @@ class CorpusEpoch:
     through it) publishes a new epoch as its LAST step, with a single
     attribute assignment: readers racing an append see either the old
     or the new epoch, never a torn one.  Because the store, the split
-    tree and the device mirrors are all strictly append-only (prefixes
-    are never rewritten), the frontier is cheap — ``n_rows`` alone
+    tree and the device mirrors are all strictly append-only (a
+    prefix's values never change), the frontier is cheap — ``n_rows`` alone
     pins everything a reader needs:
 
     * store arrays: rows ``[0, n_rows)`` are complete and immutable
@@ -73,9 +73,9 @@ class CorpusEpoch:
     * split tree: item ids are assigned monotonically, so an as-of
       read is the row-count filter ``id < n_rows`` during traversal
       (``SplitTree.seed_candidates`` / ``collect_bounds``);
-    * round-robin mirrors: the shard head/tail split at this frontier
-      is ``head = (n_rows // n_shards) * n_shards`` — derived per
-      sweep, with ids ``>= n_rows`` masked to +inf on device.
+    * round-robin mirrors: every row ``[0, n_rows)`` is on the device
+      (row ``i`` on shard ``i % n_shards``, slot ``i // n_shards``),
+      with ids ``>= n_rows`` masked to +inf on device.
 
     ``epoch`` is the store version at publication (monotone counter);
     ``index_n`` records how many items the attached index covered when

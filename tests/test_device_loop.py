@@ -8,10 +8,11 @@ more than there are candidates, at 1 and 4 (virtual) devices, both must
 give identical indices, distances, per-query raw accesses, round counts
 and per round the same active queries, rows examined and k-th bests.
 Planted ties at the k-th distance, an epoch mask and index seed
-frontiers are covered; an ``on_verified`` callback or a host tail keeps
-the host loop, and the engagement counters then read zero.  The square
-root inside the loop rounds as numpy's does, whatever the backend's
-``sqrt`` rounds to.
+frontiers are covered; an ``on_verified`` callback keeps the host loop,
+and the engagement counters then read zero.  A row count that is not a
+multiple of the shard count runs the device loop too.  The square root
+inside the loop rounds as numpy's does, whatever the backend's ``sqrt``
+rounds to.
 
 Each device count runs in one subprocess (XLA's device count is
 process-global); its results are checked case by case.
@@ -33,7 +34,7 @@ KS = (1, 4, "all")
 FIELDS = ("indices", "distances", "raw_accesses", "rounds", "active",
           "examined", "kth")
 
-_SCRIPT = """
+_PRELUDE = """
     import json
     import numpy as np
     import jax, jax.numpy as jnp
@@ -86,6 +87,20 @@ _SCRIPT = """
                 "kth": [r[2] for r in ra] == [r[2] for r in rb],
             }}
 
+    # the session's counter: one a dispatch whose rounds ran on device
+    def served(engine, Q):
+        reg = MetricsRegistry()
+        sess = MatchSession(engine, metrics=reg, window_s=0.05,
+                            max_batch=8)
+        reqs = [sess.submit(q, k=4) for q in Q]
+        sess.start()
+        assert all(r.wait(120) and r.ok for r in reqs)
+        sess.close()
+        c = reg.snapshot()["counters"]
+        return c.get("serve.device_loop", 0), c.get("serve.batches", 0)
+"""
+
+_SCRIPT = """
     out = {}
     for i, name in enumerate(%(techs)r):
         n = 96 + 4 * i                       # a multiple of 1 and 4
@@ -131,36 +146,29 @@ _SCRIPT = """
         "same": bool(np.array_equal(res.indices, base.indices)
                      and np.array_equal(res.distances, base.distances))}
 
-    # the session's counter: one a dispatch whose rounds ran on device
-    def served(engine, Q):
-        reg = MetricsRegistry()
-        sess = MatchSession(engine, metrics=reg, window_s=0.05,
-                            max_batch=8)
-        reqs = [sess.submit(q, k=4) for q in Q]
-        sess.start()
-        assert all(r.wait(120) and r.ok for r in reqs)
-        sess.close()
-        c = reg.snapshot()["counters"]
-        return c.get("serve.device_loop", 0), c.get("serve.batches", 0)
-
     out["session"] = served(dev, X[:5])
+    print(json.dumps(out))
+"""
 
-    # a host tail (rows past the shard-divisible head) keeps the host
-    # loop, with the same answers
-    if S > 1:
-        dev, host = engines("ssax", X[5:98])  # 93 rows: a tail of 1
-        out["tail"] = compare(dev, host, X[:5], 8)
-        out["tail_session"] = served(dev, X[:5])
+# 93 rows: ragged over 2 and 4 shards (the last slot holds 1 row)
+_RAGGED = """
+    X = season_dataset(98, T, L, 0.7, per_series_strength=True, seed=41)
+    out = {}
+    for name in %(techs)r:
+        dev, host = engines(name, X[5:])
+        out[name] = compare(dev, host, X[:5], 8)
+        out[name]["session"] = served(dev, X[:5])
     print(json.dumps(out))
 """
 
 
-def _run(devices: int) -> dict:
+def _run(devices: int, body: str = _SCRIPT) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    code = textwrap.dedent(_SCRIPT) % {"techs": TECHS}
+    code = textwrap.dedent(_PRELUDE) + textwrap.dedent(body) % {
+        "techs": TECHS}
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=900, env=env)
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
@@ -170,6 +178,12 @@ def _run(devices: int) -> dict:
 @pytest.fixture(scope="module", params=[1, 4], ids=["1dev", "4dev"])
 def results(request):
     return request.param, _run(request.param)
+
+
+@pytest.fixture(scope="module", params=[1, 2, 4],
+                ids=["1dev", "2dev", "4dev"])
+def ragged(request):
+    return request.param, _run(request.param, _RAGGED)
 
 
 def _same(case):
@@ -219,15 +233,19 @@ def test_session_counts_the_loop_dispatches(results):
     assert batches >= 1 and loops == batches
 
 
-def test_host_tail_keeps_the_host_loop(results):
-    devices, out = results
-    if devices == 1:
-        assert "tail" not in out      # one shard holds every row
-        return
-    case = out["tail"]
-    assert case["loop"] == [False, False] and case["counter"] == [0, 0]
-    _same(case)
-    assert out["tail_session"][0] == 0 and out["tail_session"][1] >= 1
+def test_host_tail_keeps_the_host_loop(ragged):
+    """Rows past the last multiple of the shard count (once a host tail
+    that kept the host loop) are on the device: 93 rows over 1, 2 and 4
+    shards run the device loop in every dispatch, for every encoder,
+    with the host engine's answers and rounds."""
+    _, out = ragged
+    for tech in TECHS:
+        case = out[tech]
+        assert case["loop"] == [True, False], tech
+        assert case["counter"] == [1, 0], tech
+        _same(case)
+        loops, batches = case["session"]
+        assert batches >= 1 and loops == batches, tech
 
 
 def test_loop_square_root_rounds_as_numpy():

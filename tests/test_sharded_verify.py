@@ -50,7 +50,7 @@ def _run(code: str):
 
 
 def test_device_verification_bitwise_equals_host_all_encoders_shards():
-    """Whole-series: every encoder x 1/2/4 shards, ragged tail included;
+    """Whole-series: every encoder x 1/2/4 shards, ragged row counts;
     the device path returns bit-identical (indices AND distances) top-k
     while touching zero host rows, and the device shard unit matches the
     snapshot raw manifest."""
@@ -78,20 +78,22 @@ def test_device_verification_bitwise_equals_host_all_encoders_shards():
                 np.testing.assert_array_equal(r_d.indices, r_h.indices)
                 np.testing.assert_array_equal(r_d.distances,
                                               r_h.distances)
+                np.testing.assert_array_equal(r_d.raw_accesses,
+                                              r_h.raw_accesses)
                 assert r_d.store_accesses == 0, (shards, name)
                 assert r_d.store_fetches == 0 and r_d.io_seconds == 0.0
-                head = dev.sweep._head
-                assert head == (51 // shards) * shards
+                assert r_d.device_loop, (shards, name)
+                # every row is on the device, ragged or not;
                 # shard_ranges() keeps the snapshot MANIFEST semantics
                 # (contiguous on disk) while the device mirror lays
                 # rows out round-robin — two independent contracts
                 assert dev.sweep.shard_ranges() == \\
-                    _shard_ranges(head, shards), (shards, name)
+                    _shard_ranges(51, shards), (shards, name)
                 assert dev.sweep.mirror_layout == "round_robin"
                 for s in range(shards):
                     np.testing.assert_array_equal(
                         dev.sweep.owned_rows(s),
-                        np.arange(s, head, shards))
+                        np.arange(s, 51, shards))
         print("whole-series device==host OK")
     """)
     assert "whole-series device==host OK" in out
@@ -285,3 +287,121 @@ def test_device_window_verification_bitwise_equals_host():
         print("windowed device==host OK")
     """)
     assert "windowed device==host OK" in out
+
+
+def test_round_robin_mirror_ragged_appends_equal_one_shot():
+    """Ragged appends of 1, S-1, S+1 and 2S+3 rows in sequence, at 2 and
+    4 shards, with and without lane padding: after each one the mirror
+    holds every row at shard ``i % S``, slot ``i // S`` (dead slots and
+    lane padding zero), the end state is bit-identical to one sync of
+    all the rows, and ``h2d_bytes`` counts exactly the rows uploaded:
+    the new ones plus those already in the first slot not yet full."""
+    out = _run("""
+        from repro.core.distributed import RoundRobinMirror
+
+        rng = np.random.default_rng(5)
+
+        def blocks(mir):
+            return np.asarray(mir.buf).reshape(
+                (mir.n_shards, mir.cap) + mir.buf.shape[1:])
+
+        for shards in (2, 4):
+            mesh = make_mesh_compat((shards,), ("data",))
+            steps = (1, shards - 1, shards + 1, 2 * shards + 3)
+            n = sum(steps)
+            cases = {
+                "raw": (rng.normal(size=(n, 200)).astype(np.float32), 128),
+                "rep": (rng.integers(0, 32, (n, 6)).astype(np.int32), 0),
+                "vec": (rng.normal(size=n).astype(np.float32), 0)}
+            for name, (X, lanes) in cases.items():
+                one = RoundRobinMirror(mesh, shards, lanes=lanes)
+                one.sync(X)
+                assert one.live == n and one.h2d_bytes == X.nbytes
+                assert one.cap == -(-n // shards)
+                mir = RoundRobinMirror(mesh, shards, lanes=lanes)
+                live = want = 0
+                for step in steps:
+                    lo = (live // shards) * shards
+                    live += step
+                    mir.sync(X[:live])
+                    mir.sync(X[:live])            # nothing new: no upload
+                    want += X[lo:live].nbytes
+                    assert mir.live == live, (shards, name, step)
+                    assert mir.h2d_bytes == want, (shards, name, step)
+                    b = blocks(mir)
+                    held = np.zeros_like(b)
+                    cols = (slice(0, X.shape[1]),) if X.ndim == 2 else ()
+                    for i in range(live):
+                        held[(i % shards, i // shards) + cols] = X[i]
+                    np.testing.assert_array_equal(b, held)
+                b, ref = blocks(mir), blocks(one)
+                np.testing.assert_array_equal(b[:, :one.cap], ref)
+                assert not b[:, one.cap:].any(), (shards, name)
+        print("ragged mirror OK")
+    """)
+    assert "ragged mirror OK" in out
+
+
+def test_ragged_ingest_keeps_the_device_loop():
+    """After an append that starts mid-slot, the served path keeps the
+    device loop in every dispatch (``serve.device_loop`` equals
+    ``serve.batches``) and answers bit-identically to the host path, at
+    2 and 4 shards; windows over a ragged row count stay bit-identical
+    to the host fetch path across such an append."""
+    out = _run("""
+        from repro.core import MatchEngine
+        from repro.core.distributed import make_engine_service
+        from repro.obs import MetricsRegistry
+        from repro.service import MatchSession
+        from repro.subseq import SubseqEngine, WindowView
+
+        X = season_dataset(n=60, T=240, L=10, strength=0.7, seed=17)
+        Q, D, extra = X[:4], X[4:41], X[41:59]  # 37 rows, then 18 more
+        enc = encoders(240)["ssax"]
+        W = season_dataset(n=9, T=610, L=10, strength=0.7, seed=23)
+        Qw = np.stack([W[0, 37:157], W[8, 250:370]])
+        for shards in (2, 4):
+            mesh = make_mesh_compat((shards,), ("data",))
+            dev = make_engine_service(enc, jnp.asarray(D), mesh,
+                                      verify="device", batch_size=8)
+            host = MatchEngine(enc, dev.store, verify="host", batch_size=8)
+            assert dev.topk(Q, k=3).device_loop   # mirrors synced at 37
+            dev.ingest(extra)
+            reg = MetricsRegistry()
+            sess = MatchSession(dev, metrics=reg, window_s=0.05,
+                                max_batch=4)
+            reqs = [sess.submit(q, k=5) for q in Q]
+            sess.start()
+            assert all(r.wait(120) and r.ok for r in reqs)
+            sess.close()
+            c = reg.snapshot()["counters"]
+            assert c["serve.batches"] >= 1
+            assert c.get("serve.device_loop", 0) == c["serve.batches"], c
+            r_h = host.topk(Q, k=5)
+            for i, r in enumerate(reqs):
+                assert r.tier_served == "linear", r.tier_served
+                np.testing.assert_array_equal(r.indices, r_h.indices[i])
+                np.testing.assert_array_equal(r.distances,
+                                              r_h.distances[i])
+            assert dev.sweep._raw_mirror.live == 55
+            # dead slots stay +inf: exactly the 55 rows are candidates
+            assert (dev.sweep.candidate_stream(Q).n_finite == 55).all()
+            assert dev.sweep.repr_distances(Q).shape == (4, 55)
+
+            view = WindowView(encoders(120)["ssax"], W[:7], stride=7)
+            e_h = SubseqEngine(view, verify="host", batch_size=128)
+            e_d = SubseqEngine(view, verify="device", mesh=mesh,
+                               batch_size=128)
+            for rows in (None, W[7:9]):            # 7 rows, then 9
+                if rows is not None:
+                    view.append(rows)
+                r_h = e_h.topk(Qw, k=4)
+                r_d = e_d.topk(Qw, k=4)
+                np.testing.assert_array_equal(r_d.window_ids,
+                                              r_h.window_ids)
+                np.testing.assert_array_equal(r_d.distances,
+                                              r_h.distances)
+                assert r_d.store_accesses == 0, shards
+        print("ragged ingest OK")
+    """)
+    assert "ragged ingest OK" in out
